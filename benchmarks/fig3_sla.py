@@ -11,6 +11,7 @@ from __future__ import annotations
 import json
 
 from benchmarks.common import eval_policy, geomean_improvement, make_env
+from repro.launch.compile_cache import use_compile_cache
 
 POLICIES = ("fcfs", "prema", "herald", "magma", "relmas")
 
@@ -66,6 +67,7 @@ def run(*, quick: bool = True, with_magma: bool = True,
 
 
 def main():
+    use_compile_cache()
     run(quick=True)
 
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 import json
 
 from benchmarks.common import eval_policy, make_env
+from repro.launch.compile_cache import use_compile_cache
 
 BWS = (16.0, 12.0, 8.0, 6.0, 4.0)
 POLICIES = ("fcfs", "prema", "herald", "relmas")
@@ -47,6 +48,7 @@ def run(*, quick: bool = True, with_magma: bool = False) -> dict:
 
 
 def main():
+    use_compile_cache()
     run(quick=True)
 
 

@@ -25,6 +25,7 @@ from repro.core.policy import PolicyConfig, actor_macs_per_timestep
 from repro.core.rollout import make_policy_period, run_episode
 from repro.costmodel.accelerators import (E_DRAM_PJ_PER_BYTE,
                                           E_GBUF_PJ_PER_BYTE, SIMBA_SMALL)
+from repro.launch.compile_cache import use_compile_cache
 
 HIDDENS = (64, 128, 256, 512)
 PERIODS_US = (250.0, 500.0, 1000.0)
@@ -95,6 +96,7 @@ def run(*, quick: bool = True) -> dict:
 
 
 def main():
+    use_compile_cache()
     run(quick=True)
 
 

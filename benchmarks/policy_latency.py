@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import policy as P
+from repro.launch.compile_cache import use_compile_cache
 
 ALEXNET_MACS = 714_188_480     # conv+fc MACs of AlexNet-227
 
@@ -95,6 +96,7 @@ def run_serving(*, streams: int = 8, periods: int = 20, max_rq: int = 32,
 
 
 def main():
+    use_compile_cache()
     run()
     run_serving()
 

@@ -47,24 +47,23 @@ regression guard does).
 
 ``train_throughput`` additionally carries a ``devices`` scaling
 subsection: rounds/sec and periods/sec for the SAME chunk config at
-1/2/4 devices, each measured in a subprocess with
-``--xla_force_host_platform_device_count=N`` (the ``launch/dryrun.py``
-trick) — 1 device runs the plain fused chunk, N >= 2 the mesh-sharded
-``jit``-of-``shard_map`` chunk (``core.train
-.make_sharded_train_rounds``).  One extra arm quantifies the sharding
-machinery itself at ONE device, where compute is identical and any
-delta is pure dispatch/collective overhead: ``shardmap_1dev`` (the
+1/2/4 devices, all timed in this process over
+``jax.local_devices()[:N]`` — 1 device runs the plain fused chunk,
+N >= 2 the mesh-sharded ``jit``-of-``shard_map`` chunk (``core.train
+.make_sharded_train_rounds``).  Counts above the local device count are
+left out.  On the CPU, host devices come from
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` set before the
+process starts (``scripts/ci.sh`` does).  One extra arm quantifies the
+sharding machinery itself at ONE device, where compute is identical and
+any delta is pure dispatch/collective overhead: ``shardmap_1dev`` (the
 mesh path on a 1-device mesh) — ``overhead_1dev_shardmap`` is the
-plain fused row's rounds/sec over that arm's (the pmap reference arms
-retired together with ``make_pmap_train_rounds``).  ``host_cores`` is
+plain fused row's rounds/sec over that arm's.  ``host_cores`` is
 recorded alongside: forced host devices *partition* the host's cores,
 so on a single-core machine the N-device arms serialize and
 ``scaling_2dev`` measures sharding overhead, not speedup — the section
 exists to track scaling efficiency as a trajectory, and reads as a
 true scaling curve only where ``host_cores >= N`` (or on real
-multi-accelerator hosts).  ``--devices-probe N --probe-impl IMPL`` is
-the internal child mode that times one arm and prints a
-``devices_probe,{json}`` line.
+multi-accelerator hosts).
 
 The ``fleet_scaling`` section reports batched-rollout periods/sec per
 accelerator-fleet preset (``repro.costmodel.fleets``) — small (4-SA) vs
@@ -83,19 +82,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
 import time
-
-# Episodes shard over XLA host devices (one per core). Must be set
-# before jax initializes; a no-op when jax is already imported (e.g.
-# when driven from benchmarks/run.py inside a single-device test run).
-if "jax" not in sys.modules and os.environ.get("JAX_PLATFORMS", "") != "tpu":
-    _cores = os.cpu_count() or 1
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags and _cores > 1:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={_cores}")
 
 import jax
 import jax.numpy as jnp
@@ -114,6 +101,7 @@ from repro.core.train import (make_device_mesh,
                               make_sharded_train_rounds, make_train_rounds,
                               mesh_replicate, round_keys,
                               shard_round_keys)
+from repro.launch.compile_cache import use_compile_cache
 from repro.sim import engine as engine_mod
 import repro.sim.env as env_mod
 
@@ -351,28 +339,24 @@ def run_train(*, rounds: int = 24, batch: int = 2, periods: int = 4,
     return res
 
 
-def run_devices_probe(ndev: int, *, impl: str = "", rounds: int = 24,
-                      batch: int = 4, periods: int = 4, max_rq: int = 16,
-                      max_jobs: int = 8, hidden: int = 8,
-                      updates_per_round: int = 2, batch_size: int = 4,
-                      capacity: int = 8000, sigma0: float = 0.4,
-                      sigma_min: float = 0.05, sigma_decay: float = 0.97,
-                      seed: int = 0) -> dict:
-    """Time one fused chunk of ``rounds`` rounds at ``ndev`` devices.
+def time_devices_chunk(ndev: int, impl: str, *, rounds: int = 24,
+                       batch: int = 4, periods: int = 4, max_rq: int = 16,
+                       max_jobs: int = 8, hidden: int = 8,
+                       updates_per_round: int = 2, batch_size: int = 4,
+                       capacity: int = 8000, sigma0: float = 0.4,
+                       sigma_min: float = 0.05, sigma_decay: float = 0.97,
+                       seed: int = 0) -> dict:
+    """Time one fused chunk of ``rounds`` rounds on the first ``ndev``
+    local devices.
 
-    Runs in a CHILD process forced to ``ndev`` host devices
-    (``run_train_devices`` spawns it).  ``impl`` selects the arm:
-    ``fused`` (the plain single-device chunk — ``ndev`` must be 1),
-    or ``shard_map`` (the mesh path, valid at any ``ndev`` including 1
-    — the 1-device row isolates the sharding machinery's overhead).
-    The default is ``fused`` at 1 device and ``shard_map`` otherwise.
-    Same round logic and global batch/update sizes as
-    :func:`run_train`'s AFTER arm (with ``batch`` raised so it splits
-    over 4 devices), so the 1-device fused row doubles as that arm's
-    twin.  Prints a ``devices_probe,{json}`` line for the parent.
+    ``impl`` selects the arm: ``fused`` (the plain single-device chunk
+    — ``ndev`` must be 1), or ``shard_map`` (the mesh path, valid at
+    any ``ndev`` including 1 — the 1-device row isolates the sharding
+    machinery's overhead).  Same round logic and global batch/update
+    sizes as :func:`run_train`'s AFTER arm (with ``batch`` raised so it
+    splits over 4 devices), so the 1-device fused row doubles as that
+    arm's twin.
     """
-    assert len(jax.local_devices()) >= ndev, (ndev, jax.local_devices())
-    impl = impl or ("fused" if ndev == 1 else "shard_map")
     env = make_env("light", periods=periods, max_rq=max_rq,
                    max_jobs=max_jobs)
     pcfg = P.PolicyConfig(feat_dim=env.feat_dim, act_dim=env.act_dim,
@@ -385,7 +369,6 @@ def run_devices_probe(ndev: int, *, impl: str = "", rounds: int = 24,
     keys = round_keys(seed + 1, 0, rounds)
 
     if impl == "fused":
-        assert ndev == 1, "the plain fused chunk is single-device"
         rounds_fn = make_train_rounds(env, dcfg, **kw)
 
         def chunk():
@@ -395,9 +378,7 @@ def run_devices_probe(ndev: int, *, impl: str = "", rounds: int = 24,
             out = rounds_fn(state, buf, keys, jnp.float32(sigma0), flags)
             jax.block_until_ready(out[3]["sla"])
     else:
-        devs = jax.local_devices()[:ndev]
-        assert impl == "shard_map", impl
-        mesh = make_device_mesh(devs)
+        mesh = make_device_mesh(jax.local_devices()[:ndev])
         rounds_fn = make_sharded_train_rounds(env, dcfg, mesh=mesh, **kw)
         repl = lambda t: mesh_replicate(t, mesh)
         dkeys = shard_round_keys(keys, ndev)
@@ -416,41 +397,15 @@ def run_devices_probe(ndev: int, *, impl: str = "", rounds: int = 24,
     t0 = time.perf_counter()
     chunk()
     secs = time.perf_counter() - t0
-    res = dict(devices=ndev, impl=impl, rounds=rounds, batch=batch,
-               rounds_per_sec=round(rounds / secs, 2),
-               periods_per_sec=round(rounds * batch * periods / secs, 1))
-    print("devices_probe," + json.dumps(res), flush=True)
-    return res
+    return dict(devices=ndev, impl=impl, rounds=rounds, batch=batch,
+                rounds_per_sec=round(rounds / secs, 2),
+                periods_per_sec=round(rounds * batch * periods / secs, 1))
 
 
-def _spawn_probe(n: int, impl: str, rounds: int, timeout: int) -> dict:
-    env = {**os.environ,
-           "XLA_FLAGS": f"--xla_force_host_platform_device_count={n}",
-           "PYTHONPATH": os.pathsep.join(
-               [os.path.join(REPO, "src"), REPO,
-                os.environ.get("PYTHONPATH", "")])}
-    cmd = [sys.executable, "-m", "benchmarks.rollout_throughput",
-           "--devices-probe", str(n), "--probe-impl", impl,
-           "--train-rounds", str(rounds)]
-    r = subprocess.run(cmd, env=env, cwd=REPO, capture_output=True,
-                       text=True, timeout=timeout)
-    line = next((l for l in r.stdout.splitlines()
-                 if l.startswith("devices_probe,")), None)
-    if r.returncode != 0 or line is None:
-        raise RuntimeError(f"devices probe at {n} ({impl}) failed:\n"
-                           f"{r.stdout[-2000:]}{r.stderr[-2000:]}")
-    return json.loads(line.split(",", 1)[1])
-
-
-def run_train_devices(counts=(1, 2, 4), *, rounds: int = 24,
-                      timeout: int = 900) -> dict:
-    """The ``train_throughput.devices`` scaling section.
-
-    Spawns one child per (device count, impl) with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set before
-    the child imports jax — same trick as ``launch/dryrun.py``; the
-    module's own import-time flag guard yields to a pre-set value) and
-    collects each child's ``devices_probe`` record:
+def run_train_devices(counts=(1, 2, 4), *, rounds: int = 24) -> dict:
+    """The ``train_throughput.devices`` scaling section, timed in this
+    process on ``jax.local_devices()[:N]`` for each count ``N`` that
+    the process has devices for:
 
     - ``counts``: the scaling curve — the plain fused chunk at 1
       device, the mesh-sharded shard_map chunk at every N >= 2;
@@ -459,20 +414,23 @@ def run_train_devices(counts=(1, 2, 4), *, rounds: int = 24,
       ``overhead_1dev_shardmap`` (fused rounds/sec over the arm's)
       isolates what the sharding machinery itself costs;
     - ``scaling_2dev``: shard_map 2-device over fused 1-device
-      rounds/sec; ``host_cores`` qualifies it — forced host devices
-      split the physical cores, so the ratio is a real concurrency
-      measure only when ``host_cores >= N``.
+      rounds/sec (``None`` with fewer than 2 devices); ``host_cores``
+      qualifies it — forced host devices split the physical cores, so
+      the ratio is a real concurrency measure only when
+      ``host_cores >= N``.
     """
+    avail = len(jax.local_devices())
     out: dict[str, dict] = {}
     for n in counts:
-        impl = "fused" if n == 1 else "shard_map"
-        out[str(n)] = _spawn_probe(n, impl, rounds, timeout)
-    sm1 = _spawn_probe(1, "shard_map", rounds, timeout)
+        if n <= avail:
+            impl = "fused" if n == 1 else "shard_map"
+            out[str(n)] = time_devices_chunk(n, impl, rounds=rounds)
+    sm1 = time_devices_chunk(1, "shard_map", rounds=rounds)
     fused_rps = out["1"]["rounds_per_sec"]
     cores = os.cpu_count() or 1
-    res = dict(counts=out, shardmap_1dev=sm1,
-               scaling_2dev=round(out["2"]["rounds_per_sec"]
-                                  / fused_rps, 2),
+    res = dict(counts=out, shardmap_1dev=sm1, local_devices=avail,
+               scaling_2dev=(round(out["2"]["rounds_per_sec"] / fused_rps, 2)
+                             if "2" in out else None),
                overhead_1dev_shardmap=round(
                    fused_rps / sm1["rounds_per_sec"], 2),
                host_cores=cores,
@@ -537,6 +495,7 @@ SECTIONS = ("rollout", "magma_throughput", "train_throughput",
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=32)
     ap.add_argument("--legacy-episodes", type=int, default=3)
@@ -568,22 +527,12 @@ def main(argv=None):
                          "guard runs --only train_throughput)")
     ap.add_argument("--train-rounds", type=int, default=24,
                     help="rounds per arm in the train_throughput section")
-    ap.add_argument("--devices-probe", type=int, default=0, metavar="N",
-                    help="internal child mode: time one chunk arm at N "
-                         "forced host devices, print devices_probe,{json} "
-                         "and exit (spawned by the devices scaling "
-                         "subsection)")
-    ap.add_argument("--probe-impl", default="",
-                    choices=("", "fused", "shard_map"),
-                    help="arm for --devices-probe: plain fused chunk or "
-                         "mesh shard_map "
-                         "(default: fused at 1 device, shard_map above)")
     ap.add_argument("--device-counts", default="1,2,4",
                     help="device counts for the train_throughput devices "
-                         "scaling subsection")
+                         "scaling subsection (counts above the local "
+                         "device count are left out)")
     ap.add_argument("--no-devices", action="store_true",
-                    help="skip the devices scaling subsection (it spawns "
-                         "one subprocess per device count)")
+                    help="skip the devices scaling subsection")
     ap.add_argument("--train-batch", type=int, default=2,
                     help="episodes per round in the train_throughput "
                          "section (its own CI-sized env, like the "
@@ -594,11 +543,6 @@ def main(argv=None):
                          "(small vs large platforms)")
     ap.add_argument("--out", default=os.path.join(REPO, "BENCH_rollout.json"))
     args = ap.parse_args(argv)
-
-    if args.devices_probe:
-        # child mode: one timed arm, no out-file write
-        return run_devices_probe(args.devices_probe, impl=args.probe_impl,
-                                 rounds=args.train_rounds)
 
     def want(section):
         if args.only is not None:

@@ -6,6 +6,7 @@ import json
 import os
 
 from benchmarks.common import REPO
+from repro.launch.compile_cache import use_compile_cache
 
 SWEEP = os.path.join(REPO, "runs", "dryrun", "all.jsonl")
 
@@ -65,6 +66,7 @@ def run(path: str = SWEEP) -> dict:
 
 
 def main():
+    use_compile_cache()
     run()
 
 

@@ -34,8 +34,11 @@ import argparse
 import json
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,

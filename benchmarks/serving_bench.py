@@ -56,6 +56,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import REPO, bench_meta
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving import (LoadGenConfig, MultiTenantService,
                            request_streams, trace_to_requests)
 from repro.sim.env import EnvConfig
@@ -202,6 +203,7 @@ SECTIONS = ("guard", "scenarios")
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--streams", type=int, default=96,
                     help="concurrent request streams (the tick's vmap "

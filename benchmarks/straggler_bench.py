@@ -18,6 +18,7 @@ import json
 
 from benchmarks.common import EVAL_LOAD, EVAL_QOS_FACTOR, eval_policy, \
     make_env
+from repro.launch.compile_cache import use_compile_cache
 from repro.sim.churn import churn_preset
 
 POLICIES = ("fcfs", "herald", "relmas")
@@ -50,6 +51,7 @@ def run(*, quick: bool = True, magnitude: float = 4.0) -> dict:
 
 
 def main():
+    use_compile_cache()
     run()
 
 

@@ -47,6 +47,7 @@ from benchmarks.common import (EVAL_LOAD, EVAL_QOS_FACTOR, REPO, bench_meta,
                                make_env)
 from repro.core import baselines as BL
 from repro.costmodel.fleets import fleet_names
+from repro.launch.compile_cache import use_compile_cache
 from repro.sim.arrivals import SCENARIOS
 from repro.sim.churn import CHURN_SCENARIOS, churn_preset
 from repro.workloads import build_registry
@@ -186,6 +187,7 @@ def run(*, quick: bool = True, smoke: bool = False, workload: str = "light",
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--full", action="store_true",
                     help="paper-sized grid (slow)")

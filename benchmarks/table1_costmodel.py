@@ -3,6 +3,7 @@ from __future__ import annotations
 
 from repro.costmodel import (DEFAULT_MAS, layer_cost)
 from repro.costmodel.layers import conv2d, fc
+from repro.launch.compile_cache import use_compile_cache
 from repro.workloads import build_registry
 
 
@@ -34,6 +35,7 @@ def run() -> dict:
 
 
 def main():
+    use_compile_cache()
     run()
 
 

@@ -61,6 +61,7 @@ from repro.core.generalist import (GeneralistSpec, build_padded_envs,
 from repro.core.rollout import evaluate_batch_baseline
 from repro.costmodel import get_fleet
 from repro.costmodel.fleets import fleet_names
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.rl_train import TrainConfig, train
 from repro.sim.arrivals import ArrivalConfig
 from repro.sim.churn import CHURN_SCENARIOS, churn_preset
@@ -276,6 +277,7 @@ def run(*, quick: bool = True, smoke: bool = False, workload: str = "light",
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--full", action="store_true",
                     help="paper-sized training budgets (slow)")

@@ -137,8 +137,11 @@ fi
 # machine-invariant 2dev/1dev scaling ratio must both regress >30% to
 # fail (and the 1/2-device rows must be present).  The shardmap_1dev
 # machinery arm's rounds/sec row is dual-condition guarded vs the
-# committed file; SKIP_BENCH=1 skips
+# committed file.  The devices section times every arm in this one
+# process, so its 4 host devices are set on the command line; SKIP_BENCH=1
+# skips
 if [ -z "${SKIP_BENCH:-}" ]; then
+  XLA_FLAGS="--xla_force_host_platform_device_count=4" \
   python -m benchmarks.rollout_throughput --only train_throughput \
     --out "$CI_TMP/BENCH_rollout_fresh.json"
   python - "$CI_TMP/BENCH_rollout_fresh.json" <<'PY'

@@ -26,7 +26,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core import policy as P
@@ -137,12 +136,10 @@ def make_rollout_batch(env: SchedulingEnv, pcfg: P.PolicyConfig,
             return collect_episodes(env, pcfg, params, states, traces,
                                     keys[0], sigma, collect)
 
-        # check_rep=False: the engine's lax.while_loop has no shard_map
-        # replication rule (jax 0.4.x); every output carries the
-        # sharded batch axis anyway
-        _srun = jax.jit(shard_map(
+        # check_vma=False: every output carries the sharded batch axis
+        _srun = jax.jit(jax.shard_map(
             _body, mesh=mesh, in_specs=(rep, spec, spec, spec, rep),
-            out_specs=spec, check_rep=False))
+            out_specs=spec, check_vma=False))
 
         def rollout_batch(params, states, traces, key, sigma):
             batch = states["t"].shape[0]

@@ -73,7 +73,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core import ddpg as D
@@ -305,16 +304,11 @@ def make_device_mesh(devices=None) -> Mesh:
     return Mesh(np.array(devices), (MESH_AXIS,))
 
 
-def replicate(tree, devices):
-    """Copy a single-device pytree onto every device (leading D axis)."""
-    return jax.device_put_replicated(tree, list(devices))
-
-
 def mesh_replicate(tree, mesh: Mesh):
     """Stack a single-device pytree D times with the leading axis
-    sharded over the mesh axis — the :func:`make_sharded_train_rounds`
-    twin of :func:`replicate` (same (D, ...) calling convention, but
-    laid out for the mesh so shard_map moves no data)."""
+    sharded over the mesh axis, the (D, ...) layout
+    :func:`make_sharded_train_rounds` takes, so shard_map moves no
+    data."""
     ndev = mesh.devices.size
     spec = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
     return jax.tree.map(
@@ -449,12 +443,11 @@ def _jit_shard_map(scan_fn, mesh: Mesh, *, n_args: int,
         return jax.tree.map(lambda x: x[None], out)
 
     in_specs = tuple(spec if i in sharded else rep for i in range(n_args))
-    # check_rep=False: the engine's lax.while_loop has no replication
-    # rule yet (jax 0.4.x); every output legitimately carries the
-    # device axis, so nothing is lost by skipping the check
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=(spec, spec, spec, spec),
-                             check_rep=False),
+    # check_vma=False: every output legitimately carries the device
+    # axis, so the varying-axes check has nothing to verify
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=(spec, spec, spec, spec),
+                                 check_vma=False),
                    donate_argnums=(0, 1))
 
 

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _lstm_seq_kernel(x_ref, m_ref, wx_ref, wh_ref, b_ref, hs_ref,
                      h_scr, c_scr):
@@ -94,7 +92,7 @@ def lstm_seq_pallas(xs, mask, wx4, wh4, b4, *, block_b: int = 128,
             pltpu.VMEM((bm, H), jnp.float32),           # h carry
             pltpu.VMEM((bm, H), jnp.float32),           # c carry
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xs, mf, wx4, wh4, b4)

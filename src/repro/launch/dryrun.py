@@ -39,7 +39,7 @@ from repro.configs.base import SHAPES
 from repro.configs.registry import (ARCHS, batch_specs, cache_specs,
                                     get_arch, shapes_for)
 from repro.launch import hlo_analysis as HA
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.telemetry.console import console_line
 from repro.models import partition as PT
 from repro.models import sharding as shd
@@ -210,7 +210,7 @@ def _mesh_from_shape(spec: str):
     """'2x4' -> (data, model) mesh; '2x2x4' -> (pod, data, model)."""
     dims = tuple(int(x) for x in spec.split("x"))
     axes = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool,

@@ -101,6 +101,7 @@ from repro.core.train import (INFO_KEYS, make_device_mesh,
                               make_train_round, make_train_rounds,
                               mesh_replicate, round_keys,
                               shard_round_keys, unreplicate)
+from repro.launch.compile_cache import use_compile_cache
 from repro.sim.arrivals import ArrivalConfig
 from repro.sim.churn import CHURN_SCENARIOS, churn_preset
 from repro.sim.env import EnvConfig, SchedulingEnv
@@ -613,6 +614,7 @@ _HELP = {
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         description="RELMAS DDPG training driver (single-dispatch fused "
                     "rounds; see module docstring / docs/ARCHITECTURE.md)",

@@ -40,6 +40,7 @@ import json
 
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.service import MultiTenantService
 from repro.sim.arrivals import ArrivalConfig
 from repro.sim.env import EnvConfig
@@ -101,6 +102,7 @@ def serve_batched(svc: MultiTenantService, args, tele) -> dict:
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="mixed",
                     choices=list(WORKLOADS) + list(LM_WORKLOADS))

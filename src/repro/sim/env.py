@@ -55,6 +55,12 @@ CHURN_POISON_US = 1.0e7
 # returned (the scan carry keeps its static structure)
 _CHURN_KEYS = ("sa_valid", "lat_mult", "bw_mult")
 
+# the primer row (feats[0]) carries each SA's busy time, clip((sa_free -
+# t) / t_s, 0, BUSY_CAP) / BUSY_CAP, in the M columns from BUSY_COL (the
+# slot rows' per-SA cost columns); see SchedulingEnv.primer_sa_busy
+BUSY_COL = 4
+BUSY_CAP = 4.0
+
 
 @dataclasses.dataclass(frozen=True)
 class EnvConfig:
@@ -103,6 +109,15 @@ class SchedulingEnv:
         self.feat_dim = 4 + 2 * self.num_sas
         self.act_dim = 1 + self.num_sas
         self.seq_len = cfg.max_rq + 1          # + primer
+
+    @property
+    def primer_sa_busy(self) -> tuple[tuple, float]:
+        """``(index, us_per_unit)``: where :meth:`encode` puts the SAs'
+        busy times in ``feats`` (row, columns) and how many µs one
+        feature unit stands for.  These features are an absolute float32
+        time less the clock, so they keep that time's rounding."""
+        return ((0, slice(BUSY_COL, BUSY_COL + self.num_sas)),
+                BUSY_CAP * self.cfg.t_s_us)
 
     # ---------------- fleet tables as data ----------------
     def bind_tables(self, *, lat=None, bw=None, en=None, min_lat=None,
@@ -263,7 +278,8 @@ class SchedulingEnv:
              c_n * v[:, None], b_n * v[:, None]], axis=-1)
         sa_busy = jnp.maximum(0.0, state["sa_free"] - t) / cfg.t_s_us
         primer = jnp.concatenate(
-            [jnp.zeros((4,)), jnp.clip(sa_busy, 0.0, 4.0) / 4.0,
+            [jnp.zeros((BUSY_COL,)),
+             jnp.clip(sa_busy, 0.0, BUSY_CAP) / BUSY_CAP,
              jnp.zeros((self.num_sas,))])[None, :]
         feats = jnp.concatenate([primer, rows], axis=0)
         mask = jnp.concatenate([jnp.array([True]), slots["valid"]])

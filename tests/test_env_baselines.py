@@ -56,6 +56,22 @@ def test_build_slots_deadline_order_and_chains(env):
             assert dep[i] == i - 1
 
 
+def test_primer_sa_busy_locates_the_busy_features(env):
+    trace, state = env.new_episode(np.random.default_rng(2))
+    busy_us = np.array([0.0, 250.0, 1000.0, 2500.0, 9000.0, 40.0])
+    state = {**state, "t": jnp.asarray(3000.0),
+             "sa_free": jnp.asarray(3000.0 + busy_us, jnp.float32)}
+    slots = env.build_slots(state, trace, cutoff=3000.0)
+    feats = np.asarray(env.encode(slots, state)[0])
+    idx, us_per_unit = env.primer_sa_busy
+    np.testing.assert_allclose(
+        feats[idx], np.minimum(busy_us, us_per_unit) / us_per_unit,
+        rtol=1e-6)
+    rest = feats[0].copy()
+    rest[idx[1]] = 0.0
+    assert not rest.any()
+
+
 def test_reward_hand_computed(env):
     """One job, one layer, hits the deadline -> alpha + gamma*slack."""
     cfg = env.cfg
