@@ -127,33 +127,46 @@ def make_serving_tick(env: SchedulingEnv, *, kind: str = "specialist",
         # commit_only: the tick discards the transition, so the engine
         # may stop at the period-boundary start horizon — committed
         # results (and therefore all queue state) stay bit-identical
+        tele = "tele" in qs
         with jax.named_scope("serving.period"):
             state, _, info = env.period(
                 qs["state"], qs["trace"],
                 lambda feats, mask, slots, st: act(params, feats, mask,
                                                    slots, st, key),
-                commit_only=True)
+                commit_only=True, engine_iters=tele)
         with jax.named_scope("serving.retire"):
             qs, out = queue_retire(env, {**qs, "state": state})
         out.update(n_admitted=n_adm, committed=info["committed"],
                    t_us=state["t"])
-        if "tele" in qs:
+        if tele:
             # across-tick device aggregates: trace-time structural gate
             # (a queue without the block compiles the identical program,
             # so telemetry-off ticks stay bit-for-bit unchanged)
             with jax.named_scope("serving.telemetry"):
                 t = qs["tele"]
                 qs = {**qs, "tele": dict(
-                    depth_hist=hist_add(t["depth_hist"], out["depth"]),
+                    t, depth_hist=hist_add(t["depth_hist"], out["depth"]),
                     committed=counter_add(t["committed"],
                                           info["committed"]),
-                    ticks=counter_add(t["ticks"], 1))}
+                    ticks=counter_add(t["ticks"], 1),
+                    engine_iters=counter_add(t["engine_iters"],
+                                             info["engine_iters"]))}
+            out["engine_iters"] = info["engine_iters"]
         return qs, out
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def tick(params, queues, adm, key):
-        return jax.vmap(one, in_axes=(None, 0, 0, 0))(
+        queues, out = jax.vmap(one, in_axes=(None, 0, 0, 0))(
             params, queues, adm, jax.random.split(key, streams))
+        if "tele" in queues:
+            # the batched engine loop runs until its slowest stream is
+            # done: its trip count is the maximum over the streams
+            with jax.named_scope("serving.telemetry"):
+                t = queues["tele"]
+                trips = jnp.max(out.pop("engine_iters"))
+                queues = {**queues, "tele": dict(
+                    t, engine_trips=counter_add(t["engine_trips"], trips))}
+        return queues, out
 
     cache[key_] = tick
     return tick
@@ -184,7 +197,9 @@ def make_serving_flush(env: SchedulingEnv, streams: int = 1):
                 tele_depth_hist=qs["tele"]["depth_hist"]["counts"],
                 tele_depth_edges=qs["tele"]["depth_hist"]["edges"],
                 tele_committed=qs["tele"]["committed"],
-                tele_ticks=qs["tele"]["ticks"])
+                tele_ticks=qs["tele"]["ticks"],
+                tele_engine_iters=qs["tele"]["engine_iters"],
+                tele_engine_trips=qs["tele"]["engine_trips"])
         return qs, out
 
     @functools.partial(jax.jit, donate_argnums=(0,))

@@ -105,7 +105,9 @@ from repro.launch.compile_cache import use_compile_cache
 from repro.sim.arrivals import ArrivalConfig
 from repro.sim.churn import CHURN_SCENARIOS, churn_preset
 from repro.sim.env import EnvConfig, SchedulingEnv
-from repro.telemetry import (console_line, make_telemetry, profile_trace)
+from repro.telemetry import (compile_counts, console_line,
+                             install_compile_counter, make_telemetry,
+                             profile_trace)
 from repro.telemetry.metrics import ROUND_TELE_KEYS
 from repro.workloads import build_registry
 
@@ -294,6 +296,8 @@ def train(cfg: TrainConfig, log_fn=print) -> dict:
                 f"--batch-episodes {cfg.batch_episodes} when sharding "
                 f"(a smaller tail round cannot split evenly over "
                 f"--devices {cfg.devices})")
+    install_compile_counter()
+    compiled0 = compile_counts()
     kind, fleets = _resolve_kind(cfg)
     # telemetry session: console sink always (through log_fn, so test
     # captures keep working), JSONL stream when --log-jsonl was given;
@@ -568,7 +572,8 @@ def train(cfg: TrainConfig, log_fn=print) -> dict:
     logf.close()
     if sharded:
         state = unreplicate(state)
-    tele.emit("run_end", best_sla=round(float(best.get("sla_rate", -1.0)), 4))
+    tele.emit("run_end", best_sla=round(float(best.get("sla_rate", -1.0)), 4),
+              compile=compile_counts(since=compiled0))
     tele.close()
     return dict(best=best, history=history, env=env, pcfg=pcfg, state=state,
                 baselines=baseline_scores, policy_kind=kind, fleets=fleets,

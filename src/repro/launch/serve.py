@@ -20,10 +20,13 @@ Two serving modes:
 Telemetry: ``--log-jsonl PATH`` streams schema'd records
 (``run_header`` / ``serve_window`` / ``serve_episode`` / ``tenant`` /
 ``serve_summary`` — see ``repro.telemetry.schema``) alongside the
-console lines; ``--window N`` sets the batched mode's tick-window
-cadence; ``--profile-dir DIR`` captures a ``jax.profiler`` trace of
-the serving loop.  ``scripts/metrics_summary.py`` validates/renders
-the stream.
+console lines, the ``run_end`` record carrying the process's compile
+counters (``repro.telemetry.compiles``); ``--window N`` sets the
+batched mode's tick-window cadence; ``--profile-dir DIR`` captures a
+``jax.profiler`` trace of the serving loop, where the host spans of
+``serve_stream`` (``serve.session``/``serve.tick``/...) and the
+``serve``/``episode`` spans lie on the device's clock.
+``scripts/metrics_summary.py`` validates/renders the stream.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --workload mixed \
@@ -44,7 +47,9 @@ from repro.launch.compile_cache import use_compile_cache
 from repro.serving.service import MultiTenantService
 from repro.sim.arrivals import ArrivalConfig
 from repro.sim.env import EnvConfig
-from repro.telemetry import console_line, make_telemetry, profile_trace
+from repro.telemetry import (compile_counts, console_line,
+                             install_compile_counter, make_telemetry,
+                             profile_trace)
 from repro.workloads import build_registry, build_llm_registry, \
     LM_WORKLOADS, WORKLOADS
 
@@ -78,7 +83,8 @@ def serve_batched(svc: MultiTenantService, args, tele) -> dict:
                        n_requests=args.requests,
                        qos_factor=args.qos_factor, qos_level=args.qos)
     reqs = request_streams(svc.env, lg, args.streams, seed=9000)
-    with tele.span("serve"), profile_trace(args.profile_dir):
+    # the profiler first: a span opened before it starts is not traced
+    with profile_trace(args.profile_dir), tele.span("serve"):
         res = svc.serve_stream(reqs, tick_k=args.tick_k, seed=9000,
                                telemetry=tele, window=args.window)
     agg, st = res["aggregate"], res["stats"]
@@ -95,7 +101,7 @@ def serve_batched(svc: MultiTenantService, args, tele) -> dict:
            "streams": args.streams, "sla_rate": agg["sla_rate"],
            "counted": agg["counted"], "deferred": st["deferred"],
            "tick_p50_us": tick_p50}
-    tele.emit("run_end", summary=out)
+    tele.emit("run_end", summary=out, compile=compile_counts())
     tele.close()
     console_line(json.dumps(out))
     return out
@@ -103,6 +109,7 @@ def serve_batched(svc: MultiTenantService, args, tele) -> dict:
 
 def main(argv=None):
     use_compile_cache()
+    install_compile_counter()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="mixed",
                     choices=list(WORKLOADS) + list(LM_WORKLOADS))
@@ -179,7 +186,7 @@ def main(argv=None):
     out = {"policy": args.policy, "workload": args.workload,
            "sla_rate_mean": float(np.mean(rates)),
            "energy_uj_mean": float(np.mean(energies))}
-    tele.emit("run_end", summary=out)
+    tele.emit("run_end", summary=out, compile=compile_counts())
     tele.close()
     console_line(json.dumps(out))
     return out

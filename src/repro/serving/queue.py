@@ -50,15 +50,18 @@ def queue_telemetry_init(max_jobs: int) -> dict:
     :func:`queue_admit` / :func:`queue_retire` pass it through
     untouched (``{**qs, ...}``), the tick updates it in-graph, and
     ``make_serving_flush`` surfaces it — so across-tick aggregates
-    (queue-depth histogram, committed sub-jobs, tick count) accumulate
-    on device with zero extra host transfers.  Depth-histogram edges
-    sit at eighths of queue capacity.
+    (queue-depth histogram, committed sub-jobs, tick count, the
+    engine's loop iterations of this stream and the batched loop's
+    trip count) accumulate on device with zero extra host transfers.
+    Depth-histogram edges sit at eighths of queue capacity.
     """
     edges = [max_jobs * f for f in
              (0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875)]
     return dict(depth_hist=hist_init(edges),
                 committed=counter_init(),
-                ticks=counter_init())
+                ticks=counter_init(),
+                engine_iters=counter_init(),
+                engine_trips=counter_init())
 
 
 def queue_init(env: SchedulingEnv, telemetry: bool = False) -> dict:

@@ -47,6 +47,7 @@ from repro.sim.arrivals import ArrivalConfig
 from repro.sim.engine import INF
 from repro.sim.env import EnvConfig, SchedulingEnv
 from repro.telemetry.console import console_line
+from repro.telemetry.profiler import trace_span
 
 
 def per_tenant_metrics(env: SchedulingEnv, state, trace) -> dict[str, dict]:
@@ -230,12 +231,27 @@ class MultiTenantService:
         ``tenant`` table aggregated across streams, and a
         ``serve_summary`` — all computed from values the loop already
         transfers, so the telemetry session adds zero device syncs.
+
+        Host spans on the profiler's clock (:func:`repro.telemetry.
+        trace_span`; one context manager each when no profiler runs):
+        ``serve.session`` over the call, holding ``serve.resolve``
+        (request resolution), ``serve.setup`` (queues and keys), one
+        ``serve.tick`` step per tick (its ``serve.stage``,
+        ``serve.dispatch``, ``serve.readback`` and ``serve.record``)
+        and ``serve.flush``.
         """
         if request_streams and isinstance(request_streams[0], Request):
             request_streams = [request_streams]
         S = len(request_streams)
         if S == 0:
             raise ValueError("no request streams given")
+        with trace_span("serve.session", streams=S):
+            return self._serve_stream(request_streams, tick_k, ticks, seed,
+                                      telemetry, window)
+
+    def _serve_stream(self, request_streams, tick_k, ticks, seed,
+                      telemetry, window) -> dict:
+        S = len(request_streams)
         names = self.env.registry.model_names
         # resolve every request up front into per-stream column arrays,
         # arrival-sorted.  Admission consumes staged rows FIFO in this
@@ -250,18 +266,24 @@ class MultiTenantService:
                     arrival=np.full((S, N), np.float32(INF), np.float32),
                     deadline=np.full((S, N), np.float32(INF), np.float32),
                     q=np.ones((S, N), np.float32))
-        for s, stream in enumerate(request_streams):
-            for j, r in enumerate(sorted(stream,
-                                         key=lambda r: r.arrival_us)):
-                mid, arr, dl, q = resolve_request(r, names)
-                cols["rid"][s, j] = r.rid
-                cols["model"][s, j] = mid
-                cols["arrival"][s, j] = arr
-                cols["deadline"][s, j] = dl
-                cols["q"][s, j] = q
-        tick, flush, queues = self._tick_fns(
-            S, device_telemetry=telemetry is not None)
+        with trace_span("serve.resolve"):
+            for s, stream in enumerate(request_streams):
+                for j, r in enumerate(sorted(stream,
+                                             key=lambda r: r.arrival_us)):
+                    mid, arr, dl, q = resolve_request(r, names)
+                    cols["rid"][s, j] = r.rid
+                    cols["model"][s, j] = mid
+                    cols["arrival"][s, j] = arr
+                    cols["deadline"][s, j] = dl
+                    cols["q"][s, j] = q
         n_ticks = ticks if ticks is not None else self.env.cfg.periods
+        with trace_span("serve.setup"):
+            tick, flush, queues = self._tick_fns(
+                S, device_telemetry=telemetry is not None)
+            # all per-tick keys drawn up front: a host-side split per
+            # tick would cost two extra dispatches inside the serving loop
+            keys = np.asarray(jax.random.split(jax.random.PRNGKey(seed),
+                                               n_ticks))
         t_s = float(self.env.cfg.t_s_us)
         head = np.zeros((S,), np.int64)    # first not-yet-admitted row
         completions: list[list[dict]] = [[] for _ in range(S)]
@@ -271,51 +293,57 @@ class MultiTenantService:
         win = int(window) if telemetry is not None else 0
         w_first, w_adm, w_def, w_comp, w_depth = 0, 0, 0, 0, 0
         lane = np.arange(K)
-        # all per-tick keys drawn up front: a host-side split per tick
-        # would cost two extra dispatches inside the serving loop
-        keys = np.asarray(jax.random.split(jax.random.PRNGKey(seed),
-                                           n_ticks))
         for i in range(n_ticks):
-            t_now = i * t_s
-            # each stream's backlog is cols[:, head:avail]; window the
-            # first K rows with one gather per column — no per-stream
-            # Python in the hot loop
-            avail = (cols["arrival"] <= t_now).sum(axis=1)
-            n_stage = np.minimum(avail - head, K)
-            idx = np.minimum(head[:, None] + lane[None, :], N - 1)
-            valid = lane[None, :] < n_stage[:, None]
-            adm = {k: np.take_along_axis(cols[k], idx, axis=1)
-                   for k in ("model", "arrival", "deadline", "q", "rid")}
-            adm["valid"] = valid
-            t0 = time.perf_counter()
-            queues, out = tick(self.params, queues, adm, keys[i])
-            n_adm = np.asarray(out["n_admitted"])
-            comp = np.asarray(out["completed"])
-            tick_wall_us.append((time.perf_counter() - t0) * 1e6)
-            head += n_adm
-            admitted += int(n_adm.sum())
-            deferred += int((n_stage - n_adm).sum())
-            depth_sum += int(np.asarray(out["depth"]).sum())
-            if win:
-                w_adm += int(n_adm.sum())
-                w_def += int((n_stage - n_adm).sum())
-                w_comp += int(comp.sum())
-                w_depth += int(np.asarray(out["depth"]).sum())
-                if i + 1 - w_first >= win or i == n_ticks - 1:
-                    w_wall = tick_wall_us[w_first:i + 1]
-                    telemetry.emit(
-                        "serve_window", tick_first=w_first, tick_last=i,
-                        tick_p50_us=float(np.percentile(w_wall, 50)),
-                        tick_p99_us=float(np.percentile(w_wall, 99)),
-                        admitted=w_adm, deferred=w_def, completed=w_comp,
-                        mean_depth=w_depth / max(len(w_wall) * S, 1))
-                    w_first, w_adm, w_def, w_comp, w_depth = \
-                        i + 1, 0, 0, 0, 0
-            if comp.any():
-                self._record(out, comp, completions)
-        queues, fout = flush(queues)
-        final = jax.tree.map(np.asarray, fout)
-        self._record(final, final["completed"], completions)
+            with trace_span("serve.tick", step_num=i):
+                t_now = i * t_s
+                # each stream's backlog is cols[:, head:avail]; window the
+                # first K rows with one gather per column — no per-stream
+                # Python in the hot loop
+                with trace_span("serve.stage"):
+                    avail = (cols["arrival"] <= t_now).sum(axis=1)
+                    n_stage = np.minimum(avail - head, K)
+                    idx = np.minimum(head[:, None] + lane[None, :], N - 1)
+                    valid = lane[None, :] < n_stage[:, None]
+                    adm = {k: np.take_along_axis(cols[k], idx, axis=1)
+                           for k in ("model", "arrival", "deadline", "q",
+                                     "rid")}
+                    adm["valid"] = valid
+                # tick_wall_us times the dispatch and the first two
+                # readbacks: the decision back on the host
+                with trace_span("serve.dispatch"):
+                    t0 = time.perf_counter()
+                    queues, out = tick(self.params, queues, adm, keys[i])
+                with trace_span("serve.readback"):
+                    n_adm = np.asarray(out["n_admitted"])
+                    comp = np.asarray(out["completed"])
+                    tick_wall_us.append((time.perf_counter() - t0) * 1e6)
+                    depth = int(np.asarray(out["depth"]).sum())
+                head += n_adm
+                admitted += int(n_adm.sum())
+                deferred += int((n_stage - n_adm).sum())
+                depth_sum += depth
+                if win:
+                    w_adm += int(n_adm.sum())
+                    w_def += int((n_stage - n_adm).sum())
+                    w_comp += int(comp.sum())
+                    w_depth += depth
+                    if i + 1 - w_first >= win or i == n_ticks - 1:
+                        w_wall = tick_wall_us[w_first:i + 1]
+                        telemetry.emit(
+                            "serve_window", tick_first=w_first, tick_last=i,
+                            tick_p50_us=float(np.percentile(w_wall, 50)),
+                            tick_p99_us=float(np.percentile(w_wall, 99)),
+                            admitted=w_adm, deferred=w_def, completed=w_comp,
+                            mean_depth=w_depth / max(len(w_wall) * S, 1))
+                        w_first, w_adm, w_def, w_comp, w_depth = \
+                            i + 1, 0, 0, 0, 0
+                if comp.any():
+                    with trace_span("serve.record"):
+                        self._record(out, comp, completions)
+        with trace_span("serve.flush"):
+            queues, fout = flush(queues)
+            final = jax.tree.map(np.asarray, fout)
+            self._record(final, final["completed"], completions)
         metrics = []
         for s in range(S):
             m = dict(hits=float(final["hits"][s]),
@@ -344,7 +372,9 @@ class MultiTenantService:
                 depth_hist=final["tele_depth_hist"].sum(axis=0).tolist(),
                 depth_edges=final["tele_depth_edges"][0].tolist(),
                 committed=int(final["tele_committed"].sum()),
-                ticks=int(final["tele_ticks"][0]))
+                ticks=int(final["tele_ticks"][0]),
+                engine_iters=int(final["tele_engine_iters"].sum()),
+                engine_trips=int(final["tele_engine_trips"][0]))
         if telemetry is not None:
             ten_counted = final["ten_counted"].sum(axis=0)
             ten_hit = final["ten_hit"].sum(axis=0)

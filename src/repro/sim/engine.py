@@ -143,11 +143,18 @@ def commit_period_np(start, finish, valid, assign, t_s, num_sas):
 # --------------------------------------------------------------------------
 @functools.partial(jax.jit,
                    static_argnames=("num_sas", "max_iters",
-                                    "stop_start_after"))
+                                    "stop_start_after", "return_iters"))
 def simulate_jax(valid, assign, prio, cost, bw, dep, ready, sa_free, B,
                  *, num_sas: int, max_iters: int | None = None,
-                 stop_start_after: float | None = None):
+                 stop_start_after: float | None = None,
+                 return_iters: bool = False):
     """Fixed-shape JAX twin of :func:`simulate_np`. float32, (start, finish).
+
+    ``return_iters=True`` also returns the event loop's iteration count
+    ``(start, finish, iters)``: under ``vmap`` the batched loop runs
+    until its slowest lane is done, so the maximum over the batch is
+    the batched loop's trip count (the serving tick's ``tele`` block
+    counts both).
 
     ``stop_start_after``: optional event-loop early exit for callers
     that only consume SJs *starting* before this time (the serving
@@ -241,15 +248,17 @@ def simulate_jax(valid, assign, prio, cost, bw, dep, ready, sa_free, B,
     init = (jnp.array(0), jnp.array(0.0, jnp.float32),
             jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.zeros(n, jnp.float32),
             jnp.full(n, INF, jnp.float32), jnp.full(n, INF, jnp.float32))
-    *_, start, finish = jax.lax.while_loop(cond, body, init)
-    return start, finish
+    it, *_, start, finish = jax.lax.while_loop(cond, body, init)
+    return (start, finish, it) if return_iters else (start, finish)
 
 
 @functools.partial(jax.jit, static_argnames=("num_sas", "max_iters",
-                                             "stop_start_after"))
+                                             "stop_start_after",
+                                             "return_iters"))
 def simulate_jax_segments(valid, assign, prio, cost, bw, dep, ready, sa_free,
                           B, *, num_sas: int, max_iters: int | None = None,
-                          stop_start_after: float | None = None):
+                          stop_start_after: float | None = None,
+                          return_iters: bool = False):
     """Seed implementation of :func:`simulate_jax` (jax.ops.segment_*).
 
     Kept verbatim as (a) the "before" arm of
@@ -326,5 +335,5 @@ def simulate_jax_segments(valid, assign, prio, cost, bw, dep, ready, sa_free,
     init = (jnp.array(0), jnp.array(0.0, jnp.float32),
             jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.zeros(n, jnp.float32),
             jnp.full(n, INF, jnp.float32), jnp.full(n, INF, jnp.float32))
-    *_, start, finish = jax.lax.while_loop(cond, body, init)
-    return start, finish
+    it, *_, start, finish = jax.lax.while_loop(cond, body, init)
+    return (start, finish, it) if return_iters else (start, finish)

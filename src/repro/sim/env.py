@@ -286,8 +286,10 @@ class SchedulingEnv:
         return feats.astype(jnp.float32), mask
 
     def simulate(self, state: State, slots: Slots, prio, sa_choice,
-                 commit_only: bool = False):
-        """Engine run for the current RQ. Returns (start, finish) rel. to t.
+                 commit_only: bool = False, return_iters: bool = False):
+        """Engine run for the current RQ: ``(start, finish, cost, bw, en,
+        sa)``, times relative to t, and the engine's loop iterations
+        last when ``return_iters``.
 
         ``commit_only=True`` stops the event loop once every SJ starting
         inside the period has finished (``stop_start_after=T_s``) — the
@@ -304,12 +306,13 @@ class SchedulingEnv:
         cost = take(slots["cost_all"])
         bw = take(slots["bw_all"])
         sa_free_rel = jnp.maximum(0.0, state["sa_free"] - state["t"])
-        start, fin = simulate_jax(
+        start, fin, *iters = simulate_jax(
             slots["valid"], sa, prio, cost, bw, slots["dep"],
             slots["ready_rel"], sa_free_rel,
             jnp.float32(self.cfg.bandwidth_gbps), num_sas=self.num_sas,
-            stop_start_after=(self.cfg.t_s_us if commit_only else None))
-        return start, fin, cost, bw, take(slots["en_all"]), sa
+            stop_start_after=(self.cfg.t_s_us if commit_only else None),
+            return_iters=return_iters)
+        return (start, fin, cost, bw, take(slots["en_all"]), sa, *iters)
 
     def reward(self, state: State, slots: Slots, fin):
         cfg = self.cfg
@@ -363,7 +366,8 @@ class SchedulingEnv:
 
     # ---------------- one full period (traceable) ----------------
     def period(self, state: State, trace: Trace, act_fn,
-               commit_only: bool = False, churn=None):
+               commit_only: bool = False, churn=None,
+               engine_iters: bool = False):
         """act_fn(feats, mask, slots, state) -> (a (R,G), prio (R,), sa (R,)).
 
         Returns (new_state, transition dict, info dict).
@@ -371,7 +375,14 @@ class SchedulingEnv:
         start horizon (see :meth:`simulate`) — valid only when the
         caller discards the transition (its reward/``s2`` need every
         finish time); ``new_state`` and ``info["committed"]`` are
-        bit-identical either way.
+        bit-identical either way.  ``engine_iters=True`` adds the
+        engine's loop iterations to ``info`` (the serving tick's
+        telemetry block counts them).
+
+        The device work is named for the profiler: ``env.slots``
+        (:meth:`build_slots`), ``env.act`` (``act_fn``) and
+        ``env.engine`` (:meth:`simulate`); a named scope changes only
+        the instructions' ``op_name`` metadata.
 
         ``churn``: optional per-period churn row ``dict(valid (M,),
         lat_mult (M,), bw_mult (M,))`` (one slice of a compiled
@@ -387,21 +398,27 @@ class SchedulingEnv:
                      "bw_mult": churn["bw_mult"]}
         t = state["t"]
         state = self.mark_drops(state, trace, t)
-        slots = self.build_slots(state, trace, cutoff=t)
+        with jax.named_scope("env.slots"):
+            slots = self.build_slots(state, trace, cutoff=t)
         feats, mask = self.encode(slots, state)
-        a, prio, sa_choice = act_fn(feats, mask, slots, state)
-        start, fin, cost, bw, en, sa = self.simulate(state, slots, prio,
-                                                     sa_choice,
-                                                     commit_only=commit_only)
+        with jax.named_scope("env.act"):
+            a, prio, sa_choice = act_fn(feats, mask, slots, state)
+        with jax.named_scope("env.engine"):
+            start, fin, cost, bw, en, sa, *iters = self.simulate(
+                state, slots, prio, sa_choice, commit_only=commit_only,
+                return_iters=engine_iters)
         r = self.reward(state, slots, fin)
         new_state = self.commit(state, trace, slots, start, fin, en, sa)
         # residual-RQ-only next state (paper Sec. 4.2): cutoff at *old* t
         ns = self.mark_drops(new_state, trace, new_state["t"])
-        rslots = self.build_slots(ns, trace, cutoff=t)
+        with jax.named_scope("env.slots"):
+            rslots = self.build_slots(ns, trace, cutoff=t)
         feats2, mask2 = self.encode(rslots, ns)
         trans = dict(s=feats, mask=mask, a=a, r=r, s2=feats2, mask2=mask2)
         info = dict(reward=r,
                     committed=jnp.sum(slots["valid"] & (start < self.cfg.t_s_us)))
+        if engine_iters:
+            info["engine_iters"] = iters[0]
         if churn is not None:
             new_state = {k: v for k, v in new_state.items()
                          if k not in _CHURN_KEYS}

@@ -1,4 +1,4 @@
-"""Device-resident telemetry primitives: counters, gauges, histograms.
+"""Device-resident telemetry primitives: counters and histograms.
 
 Pure ``jnp`` pytree reducers designed to live *inside* jitted programs
 — the fused training round's ``lax.scan`` carry and the serving tick's
@@ -10,17 +10,14 @@ force a sync: every op is shape-static, traceable, and composes with
 - **Counter**: a 0-d integer; :func:`counter_add` is associative, so
   accumulating per-round inside a scan equals one bulk add (tested in
   ``tests/test_telemetry.py``).
-- **Gauge**: a 0-d float holding the *last* written value
-  (:func:`gauge_set` — e.g. replay-ring fill fraction at round end).
 - **Histogram**: fixed-bucket counts over a static edge vector
   (:func:`hist_init` / :func:`hist_add`).  Bucket ``i`` counts values
   in ``[edges[i-1], edges[i])`` with bucket ``0`` the underflow
   (``v < edges[0]``) and bucket ``len(edges)`` the overflow
-  (``v >= edges[-1]``) — the Prometheus-style cumulative quantile
-  estimate is host-side (:func:`hist_quantile`).  The add is a one-hot
-  masked reduction, not a scatter: XLA CPU lowers batched scatters to
-  serial loops (the same trick as the engine's segment ops and the
-  serving queue's admission).
+  (``v >= edges[-1]``).  The add is a one-hot masked reduction, not a
+  scatter: XLA CPU lowers batched scatters to serial loops (the same
+  trick as the engine's segment ops and the serving queue's
+  admission).
 
 Bit-neutrality contract: these reducers only ever *read* the values
 the surrounding program already computes; enabling them must not
@@ -30,7 +27,6 @@ serving tick in ``tests/test_telemetry.py``).
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 
 # default edge vectors for the in-graph aggregates the fused round and
 # serving tick maintain (see repro.core.train / repro.core.serve)
@@ -39,7 +35,7 @@ REWARD_EDGES = (-4.0, -2.0, -1.0, -0.5, 0.0, 0.5, 1.0, 2.0, 4.0)
 
 
 # ---------------------------------------------------------------------------
-# counters / gauges
+# counters
 # ---------------------------------------------------------------------------
 def counter_init(dtype=jnp.int32) -> jnp.ndarray:
     """A zeroed 0-d counter."""
@@ -49,16 +45,6 @@ def counter_init(dtype=jnp.int32) -> jnp.ndarray:
 def counter_add(c: jnp.ndarray, n=1) -> jnp.ndarray:
     """``c + n`` in the counter's dtype (associative scan reducer)."""
     return c + jnp.asarray(n).astype(c.dtype)
-
-
-def gauge_init(dtype=jnp.float32) -> jnp.ndarray:
-    """A zeroed 0-d gauge."""
-    return jnp.zeros((), dtype)
-
-
-def gauge_set(g: jnp.ndarray, v) -> jnp.ndarray:
-    """Overwrite the gauge with ``v`` (last-write-wins scan reducer)."""
-    return jnp.asarray(v).astype(g.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -94,46 +80,6 @@ def hist_add(h: dict, values, weights=None) -> dict:
         add = jnp.sum(jnp.where(hot, w[:, None], 0), axis=0,
                       dtype=h["counts"].dtype)
     return dict(edges=h["edges"], counts=h["counts"] + add)
-
-
-def hist_merge(a: dict, b: dict) -> dict:
-    """Sum two histograms over identical edges (associative)."""
-    return dict(edges=a["edges"], counts=a["counts"] + b["counts"])
-
-
-def hist_quantile(h: dict, q: float) -> float:
-    """Host-side quantile estimate by linear interpolation inside the
-    bucket the ``q``-th mass falls in (numpy; call at chunk boundaries
-    on transferred counts).  Underflow clamps to ``edges[0]``, overflow
-    to ``edges[-1]``; an empty histogram returns ``nan``."""
-    edges = np.asarray(h["edges"], np.float64)
-    counts = np.asarray(h["counts"], np.float64)
-    total = counts.sum()
-    if total <= 0:
-        return float("nan")
-    # bucket i spans [lo[i], hi[i]) with the open ends pinned to the
-    # extreme edges (we cannot estimate beyond the recorded range)
-    lo = np.concatenate([[edges[0]], edges])
-    hi = np.concatenate([edges, [edges[-1]]])
-    cum = np.cumsum(counts)
-    target = q * total
-    i = int(np.searchsorted(cum, target, side="left"))
-    i = min(i, len(counts) - 1)
-    prev = cum[i - 1] if i > 0 else 0.0
-    frac = (target - prev) / counts[i] if counts[i] > 0 else 0.0
-    return float(lo[i] + frac * (hi[i] - lo[i]))
-
-
-def hist_mean(h: dict) -> float:
-    """Host-side bucket-midpoint mean estimate (nan when empty)."""
-    edges = np.asarray(h["edges"], np.float64)
-    counts = np.asarray(h["counts"], np.float64)
-    total = counts.sum()
-    if total <= 0:
-        return float("nan")
-    lo = np.concatenate([[edges[0]], edges])
-    hi = np.concatenate([edges, [edges[-1]]])
-    return float((counts * (lo + hi) / 2.0).sum() / total)
 
 
 # ---------------------------------------------------------------------------

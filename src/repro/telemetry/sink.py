@@ -18,8 +18,10 @@ Emission happens only where the drivers already sync with the device
 device round-trips — the correctness constraint the fused-round parity
 test enforces.
 
-Spans: ``with tele.span("collect"): ...`` times a host-side section and
-emits a ``span`` record.  Sections that dispatch async device work
+Spans: ``with tele.span("collect"): ...`` times a host-side section,
+emits a ``span`` record and opens the same span on the profiler's clock
+(:func:`repro.telemetry.profiler.trace_span`), so a ``--profile-dir``
+trace shows it beside the device's work.  Sections that dispatch async device work
 should close over the result's materialization (the drivers time the
 chunk dispatch *including* the metrics transfer, which is the honest
 wall-clock cost of the round).
@@ -34,6 +36,7 @@ import time
 import uuid
 
 from repro.telemetry.console import console_line, format_record
+from repro.telemetry.profiler import trace_span
 from repro.telemetry.runmeta import run_meta
 from repro.telemetry.schema import SCHEMA_VERSION, validate_record
 
@@ -130,10 +133,12 @@ class Telemetry:
 
     @contextlib.contextmanager
     def span(self, name: str, **fields):
-        """Time a host-side section and emit a ``span`` record."""
+        """Time a host-side section, emit a ``span`` record, and open
+        the section as a profiler span with ``fields`` as its stats."""
         t0 = time.perf_counter()
         try:
-            yield
+            with trace_span(name, **fields):
+                yield
         finally:
             self.emit("span", name=name,
                       secs=round(time.perf_counter() - t0, 6), **fields)

@@ -20,10 +20,8 @@ from repro.sim.arrivals import ArrivalConfig
 from repro.sim.env import EnvConfig, SchedulingEnv
 from repro.telemetry import (ConsoleSink, JsonlSink, ListSink, SchemaError,
                              Telemetry, counter_add, counter_init,
-                             gauge_init, gauge_set, hist_add, hist_init,
-                             hist_mean, hist_merge, hist_quantile,
-                             make_telemetry, null_telemetry,
-                             validate_record)
+                             hist_add, hist_init, make_telemetry,
+                             null_telemetry, validate_record)
 from repro.telemetry.metrics import (ROUND_TELE_KEYS, round_telemetry)
 from repro.workloads import build_registry
 
@@ -85,26 +83,6 @@ def test_hist_add_weighted():
     assert np.array_equal(np.asarray(h["counts"]), oracle)
 
 
-def test_hist_quantile_within_edge_range():
-    rng = np.random.default_rng(1)
-    v = rng.normal(0.0, 1.0, size=500)
-    h = hist_add(hist_init(EDGES), v)
-    qs = [hist_quantile(h, q) for q in (0.0, 0.25, 0.5, 0.9, 1.0)]
-    for a, b in zip(qs, qs[1:]):
-        assert a <= b                           # monotone in q
-    assert all(EDGES[0] <= q <= EDGES[-1] for q in qs)
-    # the bucketed median must bracket the true median's bucket
-    med = float(np.median(v))
-    assert abs(hist_quantile(h, 0.5) - med) <= 1.0
-    assert EDGES[0] <= hist_mean(h) <= EDGES[-1]
-
-
-def test_hist_quantile_empty_is_nan():
-    h = hist_init(EDGES)
-    assert np.isnan(hist_quantile(h, 0.5))
-    assert np.isnan(hist_mean(h))
-
-
 def test_hist_init_rejects_bad_edges():
     with pytest.raises(ValueError):
         hist_init([])
@@ -125,16 +103,6 @@ def test_counter_scan_equals_bulk_add():
     assert int(scanned) == int(counter_add(counter_init(), xs.sum()))
 
 
-def test_gauge_scan_is_last_write():
-    xs = jnp.array([0.1, 0.9, 0.4], jnp.float32)
-
-    def step(g, x):
-        return gauge_set(g, x), None
-
-    scanned, _ = jax.lax.scan(step, gauge_init(), xs)
-    assert float(scanned) == float(xs[-1])
-
-
 def test_hist_scan_equals_bulk_add():
     rng = np.random.default_rng(2)
     v = jnp.asarray(rng.normal(0.5, 1.0, size=(16, 4)), jnp.float32)
@@ -146,16 +114,6 @@ def test_hist_scan_equals_bulk_add():
     bulk = hist_add(hist_init(EDGES), v)
     assert np.array_equal(np.asarray(scanned["counts"]),
                           np.asarray(bulk["counts"]))
-
-
-def test_hist_merge_matches_concat():
-    rng = np.random.default_rng(3)
-    a, b = rng.normal(size=40), rng.normal(size=25)
-    ha = hist_add(hist_init(EDGES), a)
-    hb = hist_add(hist_init(EDGES), b)
-    both = hist_add(hist_init(EDGES), np.concatenate([a, b]))
-    assert np.array_equal(np.asarray(hist_merge(ha, hb)["counts"]),
-                          np.asarray(both["counts"]))
 
 
 # ---------------------------------------------------------------------------
