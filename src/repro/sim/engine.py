@@ -18,7 +18,12 @@ Three implementations with identical semantics:
   magnitudes stay small).  Per-SA reductions are one-hot masked
   max/min instead of ``jax.ops.segment_*``: XLA CPU lowers segment
   scatters to serial per-element loops, which destroys the ``vmap``
-  vectorization the batched rollout pipeline depends on.
+  vectorization the batched rollout pipeline depends on.  Per-slot
+  lookups (``x[assign]``, ``finished[dep]``) are one-hot masked selects
+  too, so the event loop holds no gather: XLA:TPU runs a batched gather
+  at about 80 µs per 9,216 indices (96 streams x 96 slots), some 30x
+  the rest of a loop trip.  ``assign`` must lie in ``[0, num_sas)``
+  (``SchedulingEnv.simulate`` clips it so) and ``dep`` in ``[-1, n)``.
 - ``simulate_jax_segments`` — the seed's segment-op formulation, kept
   as the "before" arm of ``benchmarks/rollout_throughput.py`` and as a
   third engine for parity cross-checks.
@@ -184,31 +189,45 @@ def simulate_jax(valid, assign, prio, cost, bw, dep, ready, sa_free, B,
     sa_free = sa_free.astype(jnp.float32)
     idx = jnp.arange(n)
     # (n, M) SA one-hot, loop-invariant: per-SA reductions below are
-    # masked max/min over this instead of segment_* — XLA CPU lowers
+    # masked max/min over this instead of segment_* (XLA CPU lowers
     # segment scatters to serial per-element loops, which destroys the
-    # vmap vectorization the batched rollout pipeline relies on.
+    # vmap vectorization the batched rollout pipeline relies on), and
+    # per-slot lookups x[assign] are masked selects over it (XLA:TPU
+    # runs each batched gather at ~80 us a loop trip).  One True per row.
     onehot = assign[:, None] == jnp.arange(M)[None, :]
+    # (n, n) predecessor one-hot, loop-invariant: finished[dep] as a 0/1
+    # matvec, exact at any matmul precision (at most one non-zero term;
+    # rows with dep < 0 are all zero).  On v5e it runs ~1 us a trip
+    # faster than a masked any over a bool one-hot.
+    dephot = (dep[:, None] == idx[None, :]).astype(jnp.bfloat16)
+
+    def at_sa(x, fill, reduce):
+        """x[assign] for an (M,) x: exactly one unmasked term per row."""
+        return reduce(jnp.where(onehot, x[None, :], fill), axis=1)
+
     # loop-invariant hoists: tie-broken scores, per-slot SA-free times
     prio_tb = prio - idx.astype(jnp.float32) * 1e-6
-    enab_static = jnp.maximum(sa_free[assign], ready)
+    enab_static = jnp.maximum(at_sa(sa_free, -INF, jnp.max), ready)
 
     def body(state):
         it, t, started, finished, progress, start, finish = state
         active = started & ~finished & valid
-        dep_done = jnp.where(dep < 0, True, finished[jnp.clip(dep, 0)])
+        dep_done = jnp.where(dep < 0, True,
+                             dephot @ finished.astype(jnp.bfloat16) > 0.5)
         # ---- start phase: per-SA best ready candidate on idle SAs
         sa_busy = jnp.any(active[:, None] & onehot, axis=0)
         sa_open = ~sa_busy & (sa_free <= t + _EPS)
         cand = (valid & ~started & dep_done & (ready <= t + _EPS)
-                & sa_open[assign])
+                & at_sa(sa_open, False, jnp.any))
         # score: priority, tie-broken by lower slot index
         score = jnp.where(cand, prio_tb, -INF)
         best = jnp.max(jnp.where(onehot, score[:, None], -INF), axis=0)
-        starts_now = cand & (score >= best[assign] - 1e-9) & (score > -INF / 2)
+        starts_now = (cand & (score >= at_sa(best, -INF, jnp.max) - 1e-9)
+                      & (score > -INF / 2))
         # guard against float ties admitting 2 SJs on one SA: keep lowest idx
         first_idx = jnp.min(
             jnp.where(starts_now[:, None] & onehot, idx[:, None], n), axis=0)
-        starts_now = starts_now & (idx == first_idx[assign])
+        starts_now = starts_now & (idx == at_sa(first_idx, n, jnp.min))
         started = started | starts_now
         start = jnp.where(starts_now, t, start)
         active = active | starts_now

@@ -157,3 +157,91 @@ else:
     @pytest.mark.skip(reason="hypothesis not installed")
     def test_property_engine():
         pass
+
+
+# ---------------------------------------------------------------------------
+# gather-free event loop: batched parity with the segment-op engine
+# ---------------------------------------------------------------------------
+def _batch(seed, S, n, M, deps):
+    """S streams of n slots packed like the env: a valid prefix of jobs,
+    each job's layers contiguous; ``deps`` "chain" links each layer to
+    the one before it, "any" to a random earlier valid slot."""
+    rng = np.random.default_rng(seed)
+    valid = np.zeros((S, n), bool)
+    dep = np.full((S, n), -1, np.int32)
+    ready = np.zeros((S, n), np.float32)
+    for s in range(S):
+        k = int(rng.integers(n // 2, n + 1))
+        valid[s, :k] = True
+        i = 0
+        while i < k:
+            ready[s, i] = rng.uniform(0, 300)
+            end = min(i + int(rng.integers(1, 8)), k)
+            for j in range(i + 1, end):
+                dep[s, j] = j - 1 if deps == "chain" else rng.integers(0, j)
+            i = end
+    return dict(
+        valid=valid, assign=rng.integers(0, M, (S, n)).astype(np.int32),
+        prio=rng.uniform(-1, 1, (S, n)).astype(np.float32),
+        cost=rng.uniform(5, 200, (S, n)).astype(np.float32),
+        bw=rng.uniform(0.5, 8, (S, n)).astype(np.float32),
+        dep=dep, ready=ready,
+        sa_free=rng.uniform(0, 100, (S, M)).astype(np.float32))
+
+
+_KEYS = ("valid", "assign", "prio", "cost", "bw", "dep", "ready", "sa_free")
+
+
+@pytest.mark.parametrize("fleet", ["paper6", "8simba"])
+@pytest.mark.parametrize("stop", [None, 500.0])
+@pytest.mark.parametrize("deps", ["chain", "any"])
+def test_vmapped_engine_matches_segments_and_oracle(deps, stop, fleet):
+    """Under vmap, the one-hot engine equals the segment-op engine (which
+    keeps its gathers) bit for bit, and the float64 oracle within the
+    property test's tolerance; with ``stop_start_after`` set, on the
+    full run's committed prefix (SJs starting before the horizon)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.costmodel import get_fleet
+    from repro.sim.engine import simulate_jax_segments
+    M, B = get_fleet(fleet).num_sas, 16.0
+    b = _batch(17, 8, 32, M, deps)
+    args = [jnp.asarray(b[k]) for k in _KEYS]
+    run = lambda fn, **kw: jax.vmap(lambda *a: fn(
+        *a, jnp.float32(B), num_sas=M, return_iters=True, **kw))(*args)
+    s_seg, f_seg, it_seg = map(np.asarray, run(simulate_jax_segments))
+    s, f, it = map(np.asarray, run(simulate_jax, stop_start_after=stop))
+    keep = b["valid"] if stop is None else s_seg < stop
+    assert keep.any() and (stop is None or not keep[b["valid"]].all())
+    np.testing.assert_array_equal(s[keep], s_seg[keep])
+    np.testing.assert_array_equal(f[keep], f_seg[keep])
+    if stop is None:
+        np.testing.assert_array_equal(s, s_seg)
+        np.testing.assert_array_equal(f, f_seg)
+        np.testing.assert_array_equal(it, it_seg)
+    for i in range(len(b["valid"])):
+        s_np, f_np = simulate_np(*(b[k][i] for k in _KEYS), B)
+        k = keep[i]
+        np.testing.assert_allclose(s[i][k], s_np[k], rtol=1e-3, atol=1e-2)
+        np.testing.assert_allclose(f[i][k], f_np[k], rtol=1e-3, atol=1e-2)
+
+
+def test_vmapped_engine_lowers_without_gather():
+    """At serving shapes (96 streams x 96 slots, 6 SAs, the tick's
+    horizon) the batched engine's StableHLO holds no gather, in the
+    event loop or before it: XLA:TPU runs each batched gather at ~80 us
+    a loop trip."""
+    import jax
+    import jax.numpy as jnp
+    S, n, M = 96, 96, 6
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt)
+    args = (spec((S, n), jnp.bool_), spec((S, n), jnp.int32),
+            *(spec((S, n), jnp.float32) for _ in range(3)),
+            spec((S, n), jnp.int32), spec((S, n), jnp.float32),
+            spec((S, M), jnp.float32))
+    engine = jax.jit(jax.vmap(lambda *a: simulate_jax(
+        *a, jnp.float32(16.0), num_sas=M, stop_start_after=500.0,
+        return_iters=True)))
+    text = engine.lower(*args).as_text()
+    assert "stablehlo.while" in text
+    assert "gather" not in text
