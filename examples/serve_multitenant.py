@@ -22,27 +22,31 @@ import numpy as np
 
 from repro.configs.registry import get_arch
 from repro.models.model import build_model, param_count
-from repro.serving import ContinuousBatcher, MultiTenantService, \
-    synth_requests
+from repro.serving import ContinuousBatcher, LoadGenConfig, \
+    MultiTenantService, synth_requests
+from repro.serving.loadgen import request_streams
 from repro.sim.arrivals import ArrivalConfig
 from repro.sim.env import EnvConfig
 from repro.workloads import build_llm_registry
 
 # ---------------------------------------------------------------- control
 print("=== control plane: RELMAS over LM tenants on the simulated MAS ===")
-registry = build_llm_registry("lm_light", phase="decode")
-# bandwidth_gbps left at 0: the env takes the fleet's dram_gbps
-ecfg = EnvConfig(t_s_us=2000.0, periods=24, max_rq=48, max_jobs=24)
-arr = ArrivalConfig(max_jobs=24, load=0.8, horizon_us=ecfg.horizon_us,
-                    slack_us=2 * ecfg.t_s_us)
+# whole requests: a prefill, then one decode pass per output token
+registry = build_llm_registry("lm_light")
+ecfg = EnvConfig(t_s_us=2000.0, max_rq=48, max_jobs=24)
 ckpt = os.path.join("runs", "light_medium", "best")
 svc = MultiTenantService(registry, policy="relmas",
                          ckpt_dir=ckpt if os.path.isdir(ckpt) else None,
-                         env_cfg=ecfg, arrivals=arr)
-m = svc.run_episode(seed=7)
-print(f"episode SLA satisfaction: {m['sla_rate']:.3f} "
-      f"({int(m['counted'])} jobs, {m['energy_uj'] / 1e6:.2f} J)")
-for tenant, tm in m["per_tenant"].items():
+                         env_cfg=ecfg, arrivals=ArrivalConfig(max_jobs=24,
+                                                              load=0.8))
+streams = request_streams(svc.env, LoadGenConfig(n_requests=6,
+                                                 out_median=16.0), 4, seed=7)
+res = svc.serve_stream(streams, ticks=300)
+agg = res["aggregate"]
+print(f"SLA (both limits) {agg['sla_rate']:.3f}, TTFT {agg['ttft_rate']:.3f}, "
+      f"TPOT {agg['tpot_rate']:.3f} ({agg['counted']} requests, "
+      f"{agg['energy_uj'] / 1e6:.2f} J)")
+for tenant, tm in res["metrics"][0]["per_tenant"].items():
     if tm["jobs"]:
         print(f"  {tenant:>16s}: jobs={tm['jobs']:3d} sla={tm['sla_rate']:.3f}")
 

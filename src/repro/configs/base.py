@@ -20,6 +20,14 @@ class ArchConfig:
     top_k: int = 0
     moe_every: int = 1      # every k-th layer is MoE (jamba: 2)
     capacity_factor: float = 1.25
+    moe_d_ff: int = 0       # routed/shared expert width (0: d_ff)
+    n_shared_experts: int = 0   # experts every token passes through
+    first_k_dense: int = 0  # leading layers with a dense FFN of d_ff
+    # --- latent attention (MLA, DeepSeek-V2): 0 = plain GQA ---
+    kv_lora_rank: int = 0   # the cached latent per token
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0   # the decoupled RoPE key, cached too
+    v_head_dim: int = 0
     # --- SSM (Mamba-2) ---
     ssm_state: int = 0
     ssm_expand: int = 2

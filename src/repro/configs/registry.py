@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeSpec, SHAPES
+from repro.configs.deepseek_v2_lite import FULL as DEEPSEEK_V2_LITE
 
 _MODULES = {
     "mamba2-2.7b": "mamba2_2p7b",
@@ -46,6 +47,12 @@ for _name in _MODULES:
     _m = _load(_name)
     ARCHS[_name] = _m.FULL
     SMOKES[_name] = _m.SMOKE
+
+
+# architectures served as scheduler tenants (``workloads.llm_zoo``): the
+# model stack's archs plus those with no model in ``repro.models``
+TENANT_ARCHS: dict[str, ArchConfig] = {**ARCHS,
+                                       DEEPSEEK_V2_LITE.name: DEEPSEEK_V2_LITE}
 
 
 def list_archs() -> list[str]:

@@ -111,8 +111,10 @@ def make_serving_tick(env: SchedulingEnv, *, kind: str = "specialist",
     the return value), ``adm`` stacks per-stream ``pack_admissions``
     buffers over the leading (S,) axis, and ``out`` carries per-stream
     fixed-shape results: the retire record (``completed``/``rid``/
-    ``hit``/``missed``/``finish_us``/``depth``), ``n_admitted``, the
-    period's committed-SJ count, and the post-tick sim clock ``t_us``.
+    ``hit``/``missed``/``finish_us``/``depth``, and ``t_first``/
+    ``passes_left`` where the env's tenants re-enter), ``n_admitted``,
+    the period's committed-SJ count, and the post-tick sim clock
+    ``t_us``.
     ``params`` is the actor pytree (``None``-like empty for heuristics).
     """
     key_ = ("serving_tick", kind, pcfg, baseline_fn, streams)
@@ -151,6 +153,11 @@ def make_serving_tick(env: SchedulingEnv, *, kind: str = "specialist",
                     ticks=counter_add(t["ticks"], 1),
                     engine_iters=counter_add(t["engine_iters"],
                                              info["engine_iters"]))}
+                if env.reenters:
+                    qs["tele"].update(
+                        passes=counter_add(t["passes"], info["passes"]),
+                        first_tokens=counter_add(t["first_tokens"],
+                                                 info["first_tokens"]))
             out["engine_iters"] = info["engine_iters"]
         return qs, out
 
@@ -200,6 +207,9 @@ def make_serving_flush(env: SchedulingEnv, streams: int = 1):
                 tele_ticks=qs["tele"]["ticks"],
                 tele_engine_iters=qs["tele"]["engine_iters"],
                 tele_engine_trips=qs["tele"]["engine_trips"])
+            if env.reenters:
+                out.update(tele_passes=qs["tele"]["passes"],
+                           tele_first_tokens=qs["tele"]["first_tokens"])
         return qs, out
 
     @functools.partial(jax.jit, donate_argnums=(0,))

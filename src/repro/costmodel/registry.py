@@ -23,10 +23,23 @@ class ModelTable:
     bw_gbps: np.ndarray        # (L, M)
     energy_uj: np.ndarray      # (L, M)
     deps: np.ndarray           # (L,) int32: predecessor layer idx or -1
+    # first row of the pass a job re-enters for each further output
+    # token (an LM request's decode pass); None: one pass, the chain
+    decode_start: int | None = None
 
     @property
     def num_layers(self) -> int:
         return len(self.layers)
+
+    @property
+    def min_pass_latency_us(self) -> tuple[float, float]:
+        """Contention-free latency of the first pass (up to
+        ``decode_start``: an LM request's prefill, its time to first
+        token) and of one re-entered pass (a decode step)."""
+        best = self.latency_us.min(axis=1)
+        ds = self.num_layers if self.decode_start is None \
+            else self.decode_start
+        return float(best[:ds].sum()), float(best[ds:].sum())
 
     @property
     def min_latency_us(self) -> float:
@@ -43,7 +56,8 @@ class ModelTable:
 
 
 def register_model(name: str, layers: list[LayerSpec], mas: MASConfig,
-                   deps: list[int] | None = None) -> ModelTable:
+                   deps: list[int] | None = None,
+                   decode_start: int | None = None) -> ModelTable:
     L, M = len(layers), mas.num_sas
     lat = np.zeros((L, M))
     bw = np.zeros((L, M))
@@ -54,9 +68,13 @@ def register_model(name: str, layers: list[LayerSpec], mas: MASConfig,
                 sa, layer, dram_gbps=mas.dram_gbps)
     if deps is None:
         deps = [-1] + list(range(L - 1))  # linear chain
+    if decode_start is not None and not 0 < decode_start < L:
+        raise ValueError(f"{name}: decode_start {decode_start} outside "
+                         f"the chain of {L} layers")
     return ModelTable(name=name, layers=tuple(layers), latency_us=lat,
                       bw_gbps=bw, energy_uj=en,
-                      deps=np.asarray(deps, dtype=np.int32))
+                      deps=np.asarray(deps, dtype=np.int32),
+                      decode_start=decode_start)
 
 
 class Registry:
@@ -67,6 +85,10 @@ class Registry:
       n_layers:  (num_models,)
       deps:      (num_models, Lmax)
       min_lat:   (num_models,)
+
+    and, where a model re-enters (:attr:`reenters`), ``decode_start``
+    (``n_layers`` for a one-pass model), ``min_first`` and ``min_pass``
+    (:attr:`ModelTable.min_pass_latency_us`), each ``(num_models,)``.
     """
 
     def __init__(self, mas: MASConfig):
@@ -75,8 +97,9 @@ class Registry:
         self._order: list[str] = []
 
     def register(self, name: str, layers: list[LayerSpec],
-                 deps: list[int] | None = None) -> ModelTable:
-        tab = register_model(name, layers, self.mas, deps)
+                 deps: list[int] | None = None,
+                 decode_start: int | None = None) -> ModelTable:
+        tab = register_model(name, layers, self.mas, deps, decode_start)
         self.tables[name] = tab
         self._order.append(name)
         return tab
@@ -84,6 +107,11 @@ class Registry:
     @property
     def model_names(self) -> list[str]:
         return list(self._order)
+
+    @property
+    def reenters(self) -> bool:
+        """Whether a model's jobs re-enter the queue per output token."""
+        return any(t.decode_start is not None for t in self.tables.values())
 
     def model_id(self, name: str) -> int:
         return self._order.index(name)
@@ -107,5 +135,13 @@ class Registry:
             deps[i, :L] = t.deps
             nl[i] = L
             minlat[i] = t.min_latency_us
-        return dict(lat=lat, bw=bw, en=en, deps=deps, n_layers=nl,
-                    min_lat=minlat, lmax=lmax, num_models=n, num_sas=M)
+        out = dict(lat=lat, bw=bw, en=en, deps=deps, n_layers=nl,
+                   min_lat=minlat, lmax=lmax, num_models=n, num_sas=M)
+        if self.reenters:
+            tabs = [self.tables[name] for name in self._order]
+            out["decode_start"] = np.asarray(
+                [t.num_layers if t.decode_start is None else t.decode_start
+                 for t in tabs], np.int32)
+            split = np.asarray([t.min_pass_latency_us for t in tabs])
+            out["min_first"], out["min_pass"] = split[:, 0], split[:, 1]
+        return out

@@ -2,8 +2,10 @@
 
 Schedules DNN/LM inference requests on the heterogeneous MAS with the
 chosen policy and reports global + per-tenant SLA satisfaction.
-Tenants: the paper's CNN zoo (Table 2 workloads) and/or the 10 assigned
-LM architectures (llm_zoo layerization).
+Tenants: the paper's CNN zoo (Table 2 workloads) or LM architectures
+(llm_zoo layerization) as whole requests: a prefill, then one decode
+pass per output token, judged on time to first token and time per
+output token (``lm_dsv2lite``: DeepSeek-V2-Lite at two prompt lengths).
 
 Two serving modes:
 
@@ -15,7 +17,9 @@ Two serving modes:
   ``--rate-scale``/``--requests``) and served by ONE jitted scheduling
   tick per period across all streams; prints aggregate SLA, the
   per-tenant SLA table, plus the serving telemetry (tick p50 wall
-  time, deferrals, queue depth).
+  time, deferrals, queue depth).  LM workloads always take this path:
+  their requests (output lengths, two limits) come from the load
+  generator.
 
 Telemetry: ``--log-jsonl PATH`` streams schema'd records
 (``run_header`` / ``serve_window`` / ``serve_episode`` / ``tenant`` /
@@ -31,8 +35,8 @@ batched mode's tick-window cadence; ``--profile-dir DIR`` captures a
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --workload mixed \
       --policy relmas --ckpt runs/mixed_medium/best
-  PYTHONPATH=src python -m repro.launch.serve --workload lm_mixed \
-      --policy herald --episodes 3
+  PYTHONPATH=src python -m repro.launch.serve --workload lm_dsv2lite \
+      --policy herald --streams 8 --requests 4 --t-s 500
   PYTHONPATH=src python -m repro.launch.serve --workload light \
       --batched --streams 32 --scenario burst --rate-scale 1.5
 """
@@ -56,9 +60,8 @@ from repro.workloads import build_registry, build_llm_registry, \
 
 def build_service(args) -> MultiTenantService:
     if args.workload in LM_WORKLOADS:
-        registry = build_llm_registry(
-            args.workload, phase=args.phase, seq=args.seq,
-            mas=args.fleet or "datacenter")
+        registry = build_llm_registry(args.workload, seq=args.seq,
+                                      mas=args.fleet or "datacenter")
         t_s = 2000.0                      # LM layer latencies are larger
     else:
         registry = build_registry(args.workload, mas=args.fleet or "paper6")
@@ -101,6 +104,8 @@ def serve_batched(svc: MultiTenantService, args, tele) -> dict:
            "streams": args.streams, "sla_rate": agg["sla_rate"],
            "counted": agg["counted"], "deferred": st["deferred"],
            "tick_p50_us": tick_p50}
+    if "ttft_rate" in agg:
+        out.update(ttft_rate=agg["ttft_rate"], tpot_rate=agg["tpot_rate"])
     tele.emit("run_end", summary=out, compile=compile_counts())
     tele.close()
     console_line(json.dumps(out))
@@ -132,9 +137,8 @@ def main(argv=None):
     ap.add_argument("--t-s", type=float, default=-1.0)
     ap.add_argument("--max-rq", type=int, default=96)
     ap.add_argument("--max-jobs", type=int, default=64)
-    ap.add_argument("--phase", default="decode",
-                    choices=["decode", "prefill"])
-    ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--seq", type=int, default=128,
+                    help="prompt tokens of an LM tenant named by its arch")
     ap.add_argument("--batched", action="store_true",
                     help="serve loadgen streams through the batched "
                          "single-dispatch tick instead of per-episode "
@@ -166,7 +170,7 @@ def main(argv=None):
     svc = build_service(args)
     tele = make_telemetry(jsonl_path=args.log_jsonl or None)
     tele.run_header("serve", {k: v for k, v in vars(args).items()})
-    if args.batched:
+    if args.batched or svc.env.reenters:
         return serve_batched(svc, args, tele)
     rates, energies = [], []
     with profile_trace(args.profile_dir):

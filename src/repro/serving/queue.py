@@ -25,6 +25,13 @@ ONE device dispatch (``repro.core.serve.make_serving_tick``):
   queue's numbers are bit-identical to an episode run with the full
   trace known upfront.
 
+A queue for a re-entering registry (LM requests, ``SchedulingEnv.
+reenters``, decided once from the registry) carries each job's output length ``n_out``, TPOT limit
+``tpot`` and ``decode_start`` (``ds``) in the trace, its first-token
+time beside its finish in the completion record, and TTFT and TPOT
+attainment in the accumulators; its telemetry block counts decode
+passes and first tokens.  A CNN queue has none of these leaves.
+
 A freed slot's stale per-job state is harmless by construction: every
 consumer of job rows gates on ``arrival <= t`` (INF for free slots) or
 on the done/missed flags, and admission rewrites the full row.
@@ -52,7 +59,9 @@ def queue_telemetry_init(max_jobs: int) -> dict:
     ``make_serving_flush`` surfaces it — so across-tick aggregates
     (queue-depth histogram, committed sub-jobs, tick count, the
     engine's loop iterations of this stream and the batched loop's
-    trip count) accumulate on device with zero extra host transfers.
+    trip count; a re-entering queue adds the decode passes committed and
+    the first tokens) accumulate on device with zero extra host
+    transfers.
     Depth-histogram edges sit at eighths of queue capacity.
     """
     edges = [max_jobs * f for f in
@@ -81,6 +90,10 @@ def queue_init(env: SchedulingEnv, telemetry: bool = False) -> dict:
         model=jnp.zeros((J,), jnp.int32),
         njl=jnp.zeros((J,), jnp.int32),
     )
+    if env.reenters:
+        trace.update(n_out=jnp.ones((J,), jnp.int32),
+                     tpot=jnp.zeros((J,), jnp.float32),
+                     ds=jnp.zeros((J,), jnp.int32))
     qs = dict(
         trace=trace,
         state=env.init_state(trace),
@@ -95,9 +108,22 @@ def queue_init(env: SchedulingEnv, telemetry: bool = False) -> dict:
             ten_hit=jnp.zeros((env.num_models,), jnp.int32),
         ),
     )
+    if env.reenters:
+        qs["acc"].update(ttft_hits=jnp.zeros((), jnp.int32),
+                         tpot_hits=jnp.zeros((), jnp.int32))
     if telemetry:
         qs["tele"] = queue_telemetry_init(J)
+        if env.reenters:
+            qs["tele"].update(passes=counter_init(),
+                              first_tokens=counter_init())
     return qs
+
+
+def admission_fields(env: SchedulingEnv) -> tuple[str, ...]:
+    """The staged columns :func:`queue_admit` reads from ``adm`` besides
+    ``valid``: a one-pass queue holds no output length or TPOT limit."""
+    fields = ("model", "arrival", "deadline", "q", "rid")
+    return fields + ("n_out", "tpot") if env.reenters else fields
 
 
 def queue_admit(env: SchedulingEnv, qs: dict, adm: dict) -> tuple[dict, jnp.ndarray]:
@@ -114,7 +140,8 @@ def queue_admit(env: SchedulingEnv, qs: dict, adm: dict) -> tuple[dict, jnp.ndar
     in row order (a trace replayed in arrival order with an empty queue
     reproduces the static episode's slot assignment — the parity
     anchor); the rest scatter out of bounds and are dropped, counted in
-    ``acc["rejected"]``.  Returns ``(queue, n_admitted)``.
+    ``acc["rejected"]``.  A re-entering env's rows also carry ``n_out``
+    and ``tpot``.  Returns ``(queue, n_admitted)``.
     """
     J = qs["occupied"].shape[0]
     K = adm["valid"].shape[0]
@@ -158,6 +185,14 @@ def queue_admit(env: SchedulingEnv, qs: dict, adm: dict) -> tuple[dict, jnp.ndar
              "done": put(st["done"], jnp.zeros((K,), bool)),
              "hit": put(st["hit"], jnp.zeros((K,), bool)),
              "fjob": put(st["fjob"], jnp.full((K,), INF, jnp.float32))}
+    if env.reenters:
+        trace.update(n_out=put(tr["n_out"], adm["n_out"]),
+                     tpot=put(tr["tpot"], adm["tpot"]),
+                     ds=put(tr["ds"], env.decode_start[adm["model"]]))
+        state.update(
+            passes_left=put(st["passes_left"], adm["n_out"] - 1),
+            t_first=put(st["t_first"], jnp.full((K,), INF, jnp.float32)),
+            dl=put(st["dl"], adm["deadline"]))
     n_adm = jnp.sum(take).astype(jnp.int32)
     acc = {**qs["acc"],
            "admitted": qs["acc"]["admitted"] + n_adm,
@@ -173,8 +208,12 @@ def queue_retire(env: SchedulingEnv, qs: dict) -> tuple[dict, dict]:
 
     Completed = occupied & (done | missed).  Emits a fixed-shape
     completion record (``completed`` mask over slots + the slot's
-    ``rid``/``hit``/``missed``/``finish_us`` at retire time) — the only
-    per-tick payload the host reads back.
+    ``rid``/``hit``/``missed``/``finish_us`` at retire time, and for a
+    re-entering env ``t_first``/``passes_left``) — the only per-tick
+    payload the host reads back.  A re-entering env also counts the
+    completed jobs that met the TTFT limit (first token by the TTFT
+    deadline) and the TPOT limit (done, the last token by the final
+    deadline).
     """
     st, tr = qs["state"], qs["trace"]
     completed = qs["occupied"] & (st["done"] | st["missed"])
@@ -192,6 +231,12 @@ def queue_retire(env: SchedulingEnv, qs: dict) -> tuple[dict, dict]:
                missed=st["missed"], finish_us=st["fjob"],
                depth=jnp.sum(qs["occupied"]).astype(jnp.int32)
                - jnp.sum(completed).astype(jnp.int32))
+    if env.reenters:
+        ttft = completed & (st["t_first"] <= tr["deadline"])
+        tpot = completed & st["done"] & (st["fjob"] <= st["dl"])
+        acc.update(ttft_hits=acc["ttft_hits"] + jnp.sum(ttft, dtype=jnp.int32),
+                   tpot_hits=acc["tpot_hits"] + jnp.sum(tpot, dtype=jnp.int32))
+        out.update(t_first=st["t_first"], passes_left=st["passes_left"])
     trace = {**tr, "arrival": jnp.where(completed, INF, tr["arrival"])}
     return {**qs, "trace": trace,
             "occupied": qs["occupied"] & ~completed, "acc": acc}, out
@@ -206,13 +251,20 @@ def queue_metrics(qs: dict) -> dict:
     counts admissions (every real job of a fully-replayed trace).
     """
     acc = qs["acc"]
-    return dict(
+    out = dict(
         hits=acc["hits"], counted=acc["counted"], arrived=acc["admitted"],
         sla_rate=acc["hits"] / jnp.maximum(acc["counted"], 1),
         energy_uj=qs["state"]["energy"],
         rejected=acc["rejected"],
         ten_counted=acc["ten_counted"], ten_hit=acc["ten_hit"],
     )
+    if "ttft_hits" in acc:
+        # TTFT, TPOT and both-limits attainment (``sla_rate``)
+        counted = jnp.maximum(acc["counted"], 1)
+        out.update(ttft_hits=acc["ttft_hits"], tpot_hits=acc["tpot_hits"],
+                   ttft_rate=acc["ttft_hits"] / counted,
+                   tpot_rate=acc["tpot_hits"] / counted)
+    return out
 
 
 def pack_admissions(rows, tick_k: int) -> dict[str, np.ndarray]:
@@ -220,9 +272,11 @@ def pack_admissions(rows, tick_k: int) -> dict[str, np.ndarray]:
     ``(K,)`` admission buffer of one stream's tick.
 
     ``rows`` is a sequence of ``(rid, model_id, arrival_us, deadline_us,
-    q_us)`` tuples (at most ``tick_k`` — the caller windows its
-    backlog); the returned dict is the ``adm`` argument of
-    :func:`queue_admit`.
+    q_us)`` tuples, optionally with ``n_out`` and ``tpot_us`` after them
+    (else 1 and 0: one pass), at most ``tick_k`` of them (the caller
+    windows its backlog); the returned dict is the ``adm`` argument of
+    :func:`queue_admit`, which reads ``n_out`` and ``tpot`` only where
+    the tenants re-enter.
     """
     n = len(rows)
     if n > tick_k:
@@ -232,12 +286,16 @@ def pack_admissions(rows, tick_k: int) -> dict[str, np.ndarray]:
                deadline=np.full((tick_k,), INF, np.float32),
                q=np.ones((tick_k,), np.float32),
                rid=np.full((tick_k,), -1, np.int32),
-               valid=np.zeros((tick_k,), bool))
-    for i, (rid, mid, arr, dl, q) in enumerate(rows):
+               valid=np.zeros((tick_k,), bool),
+               n_out=np.ones((tick_k,), np.int32),
+               tpot=np.zeros((tick_k,), np.float32))
+    for i, (rid, mid, arr, dl, q, *lm) in enumerate(rows):
         adm["rid"][i] = rid
         adm["model"][i] = mid
         adm["arrival"][i] = arr
         adm["deadline"][i] = dl
         adm["q"][i] = q
         adm["valid"][i] = True
+        if lm:
+            adm["n_out"][i], adm["tpot"][i] = lm
     return adm

@@ -6,10 +6,17 @@ rejects malformed requests with clear errors (unknown model id, a
 non-positive SLA budget) *before* they can scatter poisoned rows into
 the device-resident queue — a bad deadline or an out-of-range model
 index would otherwise silently corrupt every downstream SLA number.
+
+An LM request (a tenant that re-enters per output token) asks for
+``n_out`` output tokens: one prefill pass, then ``n_out - 1`` decode
+passes.  Its ``deadline_us`` is the time-to-first-token limit
+counted from arrival, and ``tpot_us`` the time-per-output-token limit:
+its last token is due ``tpot_us * (n_out - 1)`` after its first.
 """
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import numpy as np
 
@@ -26,20 +33,35 @@ class Request:
     q_us: float | None = None
     prompt: np.ndarray | None = None    # token ids (data-plane path)
     max_new: int = 16
+    # whole LM requests: output tokens (passes) and the TPOT limit
+    n_out: int = 1
+    tpot_us: float | None = None
     # filled by the service
     finish_us: float = float("inf")
     hit: bool = False
     tokens_out: list = dataclasses.field(default_factory=list)
 
 
-def resolve_request(req: Request, model_names) -> tuple[int, float, float, float]:
+class QueueRow(NamedTuple):
+    """One request as the device queue holds it."""
+    model: int
+    arrival_us: float
+    deadline_us: float
+    q_us: float
+    n_out: int
+    tpot_us: float
+
+
+def resolve_request(req: Request, model_names) -> QueueRow:
     """Validate + resolve one request into its device-queue row.
 
-    Returns ``(model_id, arrival_us, deadline_us, q_us)``.  Raises
-    ``ValueError`` for an unknown model id (tenant not served by the
-    registry) or a non-positive SLA budget (``deadline <= arrival``, or
-    an explicit ``q_us <= 0``) — the two ways a request can poison the
-    queue's env rows.
+    Raises ``ValueError`` for an unknown model id (tenant not served by
+    the registry), a non-positive SLA budget (``deadline <= arrival``, or
+    an explicit ``q_us <= 0``), fewer than one output token, or a
+    non-positive TPOT limit (required once a request asks for more than
+    one token) — the ways a request can poison the queue's env rows.
+    A queue whose tenants run one pass refuses more than one token
+    (:meth:`repro.sim.env.SchedulingEnv.check_passes`).
     """
     try:
         mid = list(model_names).index(req.tenant)
@@ -54,7 +76,20 @@ def resolve_request(req: Request, model_names) -> tuple[int, float, float, float
             f"request {req.rid} ({req.tenant}): non-positive SLA budget "
             f"(arrival={req.arrival_us}, deadline={req.deadline_us}, "
             f"q={q}); the SLA multiplier must be positive")
-    return mid, float(req.arrival_us), float(req.deadline_us), float(q)
+    n_out = int(req.n_out)
+    if n_out < 1:
+        raise ValueError(
+            f"request {req.rid} ({req.tenant}): n_out={req.n_out} output "
+            f"tokens; a request asks for at least 1")
+    tpot = req.tpot_us
+    if tpot is None and n_out == 1:
+        tpot = 0.0
+    elif tpot is None or tpot <= 0:
+        raise ValueError(
+            f"request {req.rid} ({req.tenant}): TPOT limit "
+            f"tpot_us={tpot} must be positive for {n_out} output tokens")
+    return QueueRow(mid, float(req.arrival_us), float(req.deadline_us),
+                    float(q), n_out, float(tpot))
 
 
 def synth_requests(tenants: list[str], *, n: int, horizon_us: float,

@@ -42,6 +42,7 @@ from repro.core.generalist import (PaddedEnv, load_generalist_checkpoint,
 from repro.core.rollout import make_baseline_period, make_policy_period, \
     run_episode
 from repro.costmodel.registry import Registry
+from repro.serving.queue import admission_fields
 from repro.serving.request import Request, resolve_request
 from repro.sim.arrivals import ArrivalConfig
 from repro.sim.engine import INF
@@ -216,6 +217,13 @@ class MultiTenantService:
         periods (default ``env.cfg.periods``) and then flushes: final
         drop pass + drain, exactly the reference path's closing pass.
 
+        Where the registry's tenants re-enter (LM requests), each
+        request asks for ``n_out`` tokens under a TTFT deadline and a
+        TPOT limit: its completion record carries ``t_first_us`` and
+        ``passes_left``, ``metrics`` and ``aggregate`` TTFT and TPOT
+        attainment beside ``sla_rate`` (both limits), and the device
+        telemetry block the decode passes and first tokens.
+
         Returns ``dict(metrics, aggregate, completions, stats)``:
         ``metrics`` is the per-stream list of
         :meth:`serve_episode_host`-schema dicts, ``completions`` the
@@ -265,17 +273,23 @@ class MultiTenantService:
                     model=np.zeros((S, N), np.int32),
                     arrival=np.full((S, N), np.float32(INF), np.float32),
                     deadline=np.full((S, N), np.float32(INF), np.float32),
-                    q=np.ones((S, N), np.float32))
+                    q=np.ones((S, N), np.float32),
+                    n_out=np.ones((S, N), np.int32),
+                    tpot=np.zeros((S, N), np.float32))
         with trace_span("serve.resolve"):
             for s, stream in enumerate(request_streams):
                 for j, r in enumerate(sorted(stream,
                                              key=lambda r: r.arrival_us)):
-                    mid, arr, dl, q = resolve_request(r, names)
+                    row = resolve_request(r, names)
                     cols["rid"][s, j] = r.rid
-                    cols["model"][s, j] = mid
-                    cols["arrival"][s, j] = arr
-                    cols["deadline"][s, j] = dl
-                    cols["q"][s, j] = q
+                    cols["model"][s, j] = row.model
+                    cols["arrival"][s, j] = row.arrival_us
+                    cols["deadline"][s, j] = row.deadline_us
+                    cols["q"][s, j] = row.q_us
+                    cols["n_out"][s, j] = row.n_out
+                    cols["tpot"][s, j] = row.tpot_us
+            self.env.check_passes(cols["n_out"])
+            cols = {k: cols[k] for k in admission_fields(self.env)}
         n_ticks = ticks if ticks is not None else self.env.cfg.periods
         with trace_span("serve.setup"):
             tick, flush, queues = self._tick_fns(
@@ -304,9 +318,8 @@ class MultiTenantService:
                     n_stage = np.minimum(avail - head, K)
                     idx = np.minimum(head[:, None] + lane[None, :], N - 1)
                     valid = lane[None, :] < n_stage[:, None]
-                    adm = {k: np.take_along_axis(cols[k], idx, axis=1)
-                           for k in ("model", "arrival", "deadline", "q",
-                                     "rid")}
+                    adm = {k: np.take_along_axis(v, idx, axis=1)
+                           for k, v in cols.items()}
                     adm["valid"] = valid
                 # tick_wall_us times the dispatch and the first two
                 # readbacks: the decision back on the host
@@ -351,6 +364,9 @@ class MultiTenantService:
                      arrived=float(final["arrived"][s]),
                      sla_rate=float(final["sla_rate"][s]),
                      energy_uj=float(final["energy_uj"][s]))
+            if "ttft_hits" in final:
+                m.update({k: float(final[k][s]) for k in (
+                    "ttft_hits", "tpot_hits", "ttft_rate", "tpot_rate")})
             m["per_tenant"] = _tenant_table(names, final["ten_counted"][s],
                                             final["ten_hit"][s])
             metrics.append(m)
@@ -362,6 +378,10 @@ class MultiTenantService:
             arrived=int(final["arrived"].sum()),
             energy_uj=float(final["energy_uj"].sum()),
             completed=sum(len(c) for c in completions))
+        if "ttft_hits" in final:
+            for k in ("ttft", "tpot"):
+                aggregate[k + "_rate"] = (int(final[k + "_hits"].sum())
+                                          / max(tot_c, 1))
         stats = dict(streams=S, ticks=n_ticks, tick_k=tick_k,
                      tick_wall_us=tick_wall_us, admitted=admitted,
                      deferred=deferred, unserved=unserved,
@@ -375,6 +395,10 @@ class MultiTenantService:
                 ticks=int(final["tele_ticks"][0]),
                 engine_iters=int(final["tele_engine_iters"].sum()),
                 engine_trips=int(final["tele_engine_trips"][0]))
+            if "tele_passes" in final:
+                stats["device_tele"].update(
+                    passes=int(final["tele_passes"].sum()),
+                    first_tokens=int(final["tele_first_tokens"].sum()))
         if telemetry is not None:
             ten_counted = final["ten_counted"].sum(axis=0)
             ten_hit = final["ten_hit"].sum(axis=0)
@@ -390,13 +414,21 @@ class MultiTenantService:
 
     @staticmethod
     def _record(out, comp, completions) -> None:
-        """Append one tick's completed jobs to the per-stream logs."""
+        """Append one tick's completed jobs to the per-stream logs (an LM
+        request's with its first-token time and the passes it had left)."""
         comp = np.asarray(comp)
         rid = np.asarray(out["rid"])
         hit = np.asarray(out["hit"])
         missed = np.asarray(out["missed"])
         fin = np.asarray(out["finish_us"])
+        lm = "t_first" in out
+        if lm:
+            t_first = np.asarray(out["t_first"])
+            left = np.asarray(out["passes_left"])
         for s, j in zip(*np.nonzero(comp)):
-            completions[s].append(dict(
-                rid=int(rid[s, j]), hit=bool(hit[s, j]),
-                missed=bool(missed[s, j]), finish_us=float(fin[s, j])))
+            rec = dict(rid=int(rid[s, j]), hit=bool(hit[s, j]),
+                       missed=bool(missed[s, j]), finish_us=float(fin[s, j]))
+            if lm:
+                rec.update(t_first_us=float(t_first[s, j]),
+                           passes_left=int(left[s, j]))
+            completions[s].append(rec)

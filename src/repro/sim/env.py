@@ -18,6 +18,20 @@ Each period:
   5. the paper reward is computed from the projected finish times;
   6. the transition's next state encodes the residual RQ only.
 
+Jobs that re-enter (an LM request: one prefill pass, then one decode
+pass per further output token; the registry's ``decode_start``): a
+job's table is the prefill rows then the decode-pass rows, the RQ
+offers only the current pass, and a job whose pass ends with passes
+left goes back to ``decode_start``.  Such a job carries two limits: the
+TTFT deadline (``trace["deadline"]``) before its first token, then the
+final deadline ``t_first + tpot * (n_out - 1)``; the current one
+(``state["dl"]``) drives drops, and a job hits iff it meets both.  The
+RQ orders a pass by the deadline of the token it yields (the TTFT
+deadline, then ``t_first + tpot * k`` for the ``k + 1``-th token), so
+a decode pass is due ``tpot`` after the last and does not wait behind
+every prefill whose TTFT deadline lies before the request's final one.  Whether any tenant re-enters is read from the
+registry once: without one, every method traces the one-pass program.
+
 Whole episodes are traceable too: :meth:`SchedulingEnv.episode` runs
 all periods in one ``jax.lax.scan`` (final drop pass + metrics inside
 the trace) and is ``vmap``-able over the stacked traces/states built by
@@ -103,6 +117,13 @@ class SchedulingEnv:
         self.en = jnp.asarray(d["en"], jnp.float32)
         self.n_layers = jnp.asarray(d["n_layers"], jnp.int32)
         self.min_lat = jnp.asarray(d["min_lat"], jnp.float32)
+        self.reenters = registry.reenters
+        if self.reenters:
+            self.decode_start = jnp.asarray(d["decode_start"], jnp.int32)
+            # isolated first pass and decode pass per tenant, on the
+            # host: the LM load generator's limits
+            self.min_first = np.asarray(d["min_first"], np.float32)
+            self.min_pass = np.asarray(d["min_pass"], np.float32)
         self.arrivals = arrivals or ArrivalConfig(
             max_jobs=cfg.max_jobs, horizon_us=cfg.horizon_us,
             slack_us=2.0 * cfg.t_s_us)
@@ -151,7 +172,7 @@ class SchedulingEnv:
     def init_state(self, trace: Trace) -> State:
         """Fresh per-episode state for one trace (traceable, vmap-able)."""
         J, M = self.cfg.max_jobs, self.num_sas
-        return dict(
+        state = dict(
             nls=jnp.zeros((J,), jnp.int32),
             jready=trace["arrival"],
             missed=jnp.zeros((J,), bool),
@@ -162,11 +183,35 @@ class SchedulingEnv:
             t=jnp.zeros((), jnp.float32),
             energy=jnp.zeros((), jnp.float32),
         )
+        if self.reenters:
+            state.update(passes_left=trace["n_out"] - 1,
+                         t_first=jnp.full((J,), INF, jnp.float32),
+                         dl=trace["deadline"])
+        return state
 
     def _finish_trace(self, tr: dict) -> Trace:
         trace = {k: jnp.asarray(v) for k, v in tr.items()}
         trace["njl"] = self.n_layers[trace["model"]]
+        if self.reenters:
+            # a drawn trace carries no output lengths: one pass each
+            # (the first token), unless the rows say otherwise
+            ones = jnp.ones_like(trace["model"])
+            trace.setdefault("n_out", ones)
+            trace.setdefault("tpot", jnp.zeros_like(trace["arrival"]))
+            trace["ds"] = self.decode_start[trace["model"]]
+        else:
+            self.check_passes(tr.get("n_out", 1))
+            trace.pop("n_out", None)
+            trace.pop("tpot", None)
         return trace
+
+    def check_passes(self, n_out) -> None:
+        """Refuse requests for several output tokens where no tenant
+        re-enters (each job is one pass)."""
+        if not self.reenters and np.any(np.asarray(n_out) > 1):
+            raise ValueError(
+                f"requests for up to {int(np.max(n_out))} output tokens; "
+                f"this registry's tenants run one pass and do not re-enter")
 
     def new_episode(self, rng: np.random.Generator,
                     arrivals: ArrivalConfig | None = None
@@ -203,18 +248,43 @@ class SchedulingEnv:
         return traces, jax.vmap(self.init_state)(traces)
 
     # ---------------- pure helpers (traceable) ----------------
+    def deadline(self, state: State, trace: Trace):
+        """Each job's current deadline: the trace's, or for re-entering
+        jobs the TTFT deadline until the first token, the final one
+        after it."""
+        return state["dl"] if self.reenters else trace["deadline"]
+
+    def pass_deadline(self, state: State, trace: Trace):
+        """Each job's order key: the deadline of the token its current
+        pass yields (the trace's deadline for a one-pass job)."""
+        if not self.reenters:
+            return trace["deadline"]
+        k = (trace["n_out"] - 1 - state["passes_left"]).astype(jnp.float32)
+        return jnp.where(state["t_first"] < INF / 2,
+                         state["t_first"] + trace["tpot"] * k, state["dl"])
+
+    def pass_end(self, state: State, trace: Trace):
+        """One past the last row of each job's current pass."""
+        if not self.reenters:
+            return trace["njl"]
+        return jnp.where(state["nls"] < trace["ds"], trace["ds"],
+                         trace["njl"])
+
     def mark_drops(self, state: State, trace: Trace, now) -> State:
         overdue = ((trace["arrival"] <= now) & ~state["done"]
-                   & ~state["missed"] & (trace["deadline"] < now))
+                   & ~state["missed"] & (self.deadline(state, trace) < now))
         return {**state, "missed": state["missed"] | overdue}
 
     def build_slots(self, state: State, trace: Trace, cutoff) -> Slots:
-        """Pack uncommitted layers of active jobs into R slots by deadline."""
+        """Pack uncommitted layers of active jobs into R slots by deadline
+        (of a re-entering job, the layers of its current pass only, by
+        the deadline of the token it yields)."""
         cfg, R, J = self.cfg, self.cfg.max_rq, self.cfg.max_jobs
+        deadline = self.pass_deadline(state, trace)
         active = ((trace["arrival"] <= cutoff) & ~state["done"]
                   & ~state["missed"])
-        rem = jnp.where(active, trace["njl"] - state["nls"], 0)
-        key = jnp.where(active & (rem > 0), trace["deadline"], INF)
+        rem = jnp.where(active, self.pass_end(state, trace) - state["nls"], 0)
+        key = jnp.where(active & (rem > 0), deadline, INF)
         order = jnp.argsort(key)                       # (J,)
         rem_o = rem[order]
         cum = jnp.cumsum(rem_o)
@@ -257,7 +327,7 @@ class SchedulingEnv:
                     ready_rel=ready_rel * valid,
                     cost_all=cost_all * zero, bw_all=bw_all * zero,
                     en_all=en_all * zero, model=model,
-                    deadline=trace["deadline"][job], q=trace["q"][job],
+                    deadline=deadline[job], q=trace["q"][job],
                     arrival=trace["arrival"][job])
 
     def encode(self, slots: Slots, state: State):
@@ -349,10 +419,16 @@ class SchedulingEnv:
         nls = state["nls"] + ncom
         jready = jnp.where(ncom > 0, t + jlast, state["jready"])
         arrived = trace["arrival"] <= t
-        newly_done = arrived & ~state["done"] & ~state["missed"] \
-            & (nls >= trace["njl"]) & (ncom > 0)
-        fjob = jnp.where(newly_done, jready, state["fjob"])
-        hit = state["hit"] | (newly_done & (fjob <= trace["deadline"]))
+        if self.reenters:
+            with jax.named_scope("env.reenter"):
+                nls, newly_done, fjob, hit, lm = self._reenter(
+                    state, trace, nls, ncom, jready, arrived)
+        else:
+            lm = {}
+            newly_done = arrived & ~state["done"] & ~state["missed"] \
+                & (nls >= trace["njl"]) & (ncom > 0)
+            fjob = jnp.where(newly_done, jready, state["fjob"])
+            hit = state["hit"] | (newly_done & (fjob <= trace["deadline"]))
         done = state["done"] | newly_done
         energy = state["energy"] + jnp.sum(jnp.where(committed, en, 0.0))
         sahot = sa[:, None] == jnp.arange(M)[None, :]            # (R, M)
@@ -362,7 +438,45 @@ class SchedulingEnv:
                             state["sa_free"])
         return {**state, "nls": nls, "jready": jready, "done": done,
                 "hit": hit, "fjob": fjob, "energy": energy,
-                "sa_free": sa_free, "t": t + cfg.t_s_us}
+                "sa_free": sa_free, "t": t + cfg.t_s_us, **lm}
+
+    def _reenter(self, state: State, trace: Trace, nls, ncom, jready,
+                 arrived):
+        """Pass ends of re-entering jobs at commit: ``(nls, newly_done,
+        fjob, hit, updates)``.  A job whose current pass committed its
+        last row goes back to ``decode_start`` while it has passes left,
+        and is done after its last; the first pass's end is its first
+        token (``t_first``), from which its final deadline counts.  A
+        done job hits iff its first token met the TTFT deadline and its
+        last the final one.  ``updates``: the new ``passes_left``,
+        ``t_first`` and ``dl``."""
+        ds = trace["ds"]
+        ended = (arrived & ~state["done"] & ~state["missed"] & (ncom > 0)
+                 & (nls >= self.pass_end(state, trace)))
+        first = ended & (state["nls"] < ds)
+        t_first = jnp.where(first, jready, state["t_first"])
+        final = jready + trace["tpot"] * (trace["n_out"] - 1).astype(
+            jnp.float32)
+        dl = jnp.where(first, final, state["dl"])
+        again = ended & (state["passes_left"] > 0)
+        newly_done = ended & ~again
+        fjob = jnp.where(newly_done, jready, state["fjob"])
+        hit = state["hit"] | (newly_done & (t_first <= trace["deadline"])
+                              & (fjob <= dl))
+        passes_left = jnp.where(again, state["passes_left"] - 1,
+                                state["passes_left"])
+        return (jnp.where(again, ds, nls), newly_done, fjob, hit,
+                dict(passes_left=passes_left, t_first=t_first, dl=dl))
+
+    def pass_counts(self, old: State, new: State, trace: Trace):
+        """``(decode passes, first tokens)`` that ended between two
+        states of the same jobs (a period's commit)."""
+        first = (old["t_first"] >= INF / 2) & (new["t_first"] < INF / 2)
+        ended = ((new["passes_left"] < old["passes_left"])
+                 | (new["done"] & ~old["done"]))
+        decode = ended & (old["nls"] >= trace["ds"])
+        return (jnp.sum(decode, dtype=jnp.int32),
+                jnp.sum(first, dtype=jnp.int32))
 
     # ---------------- one full period (traceable) ----------------
     def period(self, state: State, trace: Trace, act_fn,
@@ -380,9 +494,11 @@ class SchedulingEnv:
         telemetry block counts them).
 
         The device work is named for the profiler: ``env.slots``
-        (:meth:`build_slots`), ``env.act`` (``act_fn``) and
-        ``env.engine`` (:meth:`simulate`); a named scope changes only
-        the instructions' ``op_name`` metadata.
+        (:meth:`build_slots`), ``env.act`` (``act_fn``),
+        ``env.engine`` (:meth:`simulate`) and, where jobs re-enter,
+        ``env.reenter`` (their pass ends at commit, and ``info``'s
+        ``passes``/``first_tokens``); a named scope changes only the
+        instructions' ``op_name`` metadata.
 
         ``churn``: optional per-period churn row ``dict(valid (M,),
         lat_mult (M,), bw_mult (M,))`` (one slice of a compiled
@@ -419,6 +535,10 @@ class SchedulingEnv:
                     committed=jnp.sum(slots["valid"] & (start < self.cfg.t_s_us)))
         if engine_iters:
             info["engine_iters"] = iters[0]
+        if self.reenters:
+            with jax.named_scope("env.reenter"):
+                info["passes"], info["first_tokens"] = self.pass_counts(
+                    state, new_state, trace)
         if churn is not None:
             new_state = {k: v for k, v in new_state.items()
                          if k not in _CHURN_KEYS}

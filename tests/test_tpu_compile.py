@@ -97,3 +97,40 @@ def test_serving_tick_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     # the whole tick fits a 16 GB v5e many times over
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2**30
+
+
+def test_lm_serving_tick_compiles_for_v5e(one_chip):
+    """The 96-stream tick over whole DeepSeek-V2-Lite requests (jobs that
+    re-enter) at the paper6 widths, as the benchmark times and traces it
+    (no telemetry block); a fusion the chip runs keeps the re-entry's
+    scope, which ``lm.reenter_ms`` reads."""
+    import re
+
+    import numpy as np
+
+    from repro.core import policy as P
+    from repro.core.serve import make_serving_tick, queue_init_batch
+    from repro.serving.queue import pack_admissions
+    from repro.sim.env import EnvConfig, SchedulingEnv
+    from repro.workloads import build_llm_registry
+    S, K = 96, 8
+    env = SchedulingEnv(build_llm_registry("lm_dsv2lite"),
+                        EnvConfig(max_rq=96, max_jobs=64))
+    pcfg = P.PolicyConfig(feat_dim=env.feat_dim, act_dim=env.act_dim,
+                          hidden=64)
+    tick = make_serving_tick(env, kind="specialist", pcfg=pcfg, streams=S)
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: _spec(x.shape, x.dtype, one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: P.init_actor(jax.random.PRNGKey(0), pcfg)))
+    queues = on_chip(jax.eval_shape(
+        lambda: queue_init_batch(env, S)))
+    adm = on_chip({k: np.stack([v] * S) for k, v in
+                   pack_admissions([], K).items()})
+    key = _spec((2,), jnp.uint32, one_chip)
+    compiled = tick.lower(params, queues, adm, key).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2**30
+    assert re.search(r'^\s*(?:ROOT\s+)?%?[\w.\-]+ = .* fusion\(.*'
+                     r'op_name="[^"]*env\.reenter', compiled.as_text(),
+                     re.M)
