@@ -15,8 +15,10 @@ queues a full scheduling period each:
 vmapped over the stream axis inside a single ``jax.jit`` with the queue
 pytree donated — the device boundary is crossed once per tick: the
 ``(S, K)`` staging buffers go in, a compact fixed-shape completion
-record comes out.  Episode transitions are never materialized (the
-tick returns no ``trans``, XLA dead-code-eliminates the collection).
+record comes out, packed for the host into one int32 array
+(:func:`host_layout`) that the serving loop reads in one transfer.
+Episode transitions are never materialized (the tick returns no
+``trans``, XLA dead-code-eliminates the collection).
 
 Act adapters reproduce the per-period reference paths *bit-for-bit* at
 ``sigma = 0``: the specialist matches ``rollout.make_policy_period``,
@@ -40,6 +42,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import policy as P
 from repro.core.rollout import _runner_cache
@@ -101,6 +104,59 @@ def _build_act(env, kind: str, pcfg, baseline_fn):
     raise ValueError(f"unknown serving policy kind {kind!r}")
 
 
+def host_layout(env: SchedulingEnv) -> dict:
+    """Columns of the tick's packed host record ``out["host"]``.
+
+    ``out["host"]`` is one ``(S, C)`` int32 array holding every field the
+    serving loop reads of a tick; this maps each name to its column (one
+    value a stream) or its slice of ``max_jobs`` columns (one value a
+    slot).  ``flags`` holds ``completed | hit << 1 | missed << 2``;
+    ``finish_us`` and ``t_first`` hold float32 bit patterns.  The LM
+    fields ``t_first`` and ``passes_left`` are packed only where the
+    env's tenants re-enter.
+    """
+    J = env.cfg.max_jobs
+    widths = [("n_admitted", None), ("depth", None), ("flags", J),
+              ("rid", J), ("finish_us", J)]
+    if env.reenters:
+        widths += [("t_first", J), ("passes_left", J)]
+    layout, c = {}, 0
+    for name, w in widths:
+        layout[name] = c if w is None else slice(c, c + w)
+        c += 1 if w is None else w
+    return layout
+
+
+def _host_pack(env: SchedulingEnv, out: dict) -> jnp.ndarray:
+    """The ``(S, C)`` int32 host record of a vmapped tick's ``out``."""
+    i32 = lambda x: x.astype(jnp.int32)
+    bits = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
+    cols = dict(n_admitted=out["n_admitted"], depth=out["depth"],
+                flags=i32(out["completed"]) | i32(out["hit"]) << 1
+                | i32(out["missed"]) << 2,
+                rid=out["rid"], finish_us=bits(out["finish_us"]))
+    if env.reenters:
+        cols.update(t_first=bits(out["t_first"]),
+                    passes_left=out["passes_left"])
+    return jnp.concatenate(
+        [i32(cols[k]).reshape(cols[k].shape[0], -1)
+         for k in host_layout(env)], axis=1)
+
+
+def host_unpack(layout: dict, buf: np.ndarray) -> dict:
+    """The fields of one host copy of ``out["host"]`` under ``out``'s own
+    names, dtypes and values, as numpy views of ``buf`` (the flags
+    decoded into the three bool masks)."""
+    got = {k: buf[:, ix] for k, ix in layout.items()}
+    flags = got.pop("flags")
+    got.update(completed=(flags & 1) != 0, hit=(flags & 2) != 0,
+               missed=(flags & 4) != 0,
+               finish_us=got["finish_us"].view(np.float32))
+    if "t_first" in got:
+        got["t_first"] = got["t_first"].view(np.float32)
+    return got
+
+
 def make_serving_tick(env: SchedulingEnv, *, kind: str = "specialist",
                       pcfg: P.PolicyConfig | None = None,
                       baseline_fn=None, streams: int = 1):
@@ -113,8 +169,9 @@ def make_serving_tick(env: SchedulingEnv, *, kind: str = "specialist",
     fixed-shape results: the retire record (``completed``/``rid``/
     ``hit``/``missed``/``finish_us``/``depth``, and ``t_first``/
     ``passes_left`` where the env's tenants re-enter), ``n_admitted``,
-    the period's committed-SJ count, and the post-tick sim clock
-    ``t_us``.
+    the period's committed-SJ count, the post-tick sim clock ``t_us``,
+    and ``host``: the fields the serving loop reads, packed into one
+    int32 array (:func:`host_layout`, :func:`host_unpack`).
     ``params`` is the actor pytree (``None``-like empty for heuristics).
     """
     key_ = ("serving_tick", kind, pcfg, baseline_fn, streams)
@@ -173,6 +230,10 @@ def make_serving_tick(env: SchedulingEnv, *, kind: str = "specialist",
                 trips = jnp.max(out.pop("engine_iters"))
                 queues = {**queues, "tele": dict(
                     t, engine_trips=counter_add(t["engine_trips"], trips))}
+        with jax.named_scope("serving.host_pack"):
+            # behind a barrier the pack reads the tick's results and
+            # leaves the fusions that make them as they were
+            out["host"] = _host_pack(env, jax.lax.optimization_barrier(out))
         return queues, out
 
     cache[key_] = tick

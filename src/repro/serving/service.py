@@ -9,8 +9,8 @@ Deployment wrapper over ``sim.SchedulingEnv`` with two serving paths:
   requests (masked scatter), runs batched policy inference over every
   pending sub-job of every tenant, advances the contention sim, and
   retires completed jobs — the host crosses the device boundary once
-  per tick, staging ``(S, K)`` admission buffers in and draining a
-  compact completion record out.  Fed by ``serving.loadgen`` streams.
+  per tick, staging ``(S, K)`` admission buffers in and reading one
+  packed completion record out.  Fed by ``serving.loadgen`` streams.
 
 - :meth:`MultiTenantService.serve_episode_host` — the per-period
   host-loop reference (one dispatch per period, full trace known
@@ -228,7 +228,9 @@ class MultiTenantService:
         ``metrics`` is the per-stream list of
         :meth:`serve_episode_host`-schema dicts, ``completions`` the
         per-stream completion records, ``stats`` the serving telemetry
-        (per-tick wall times, admitted/deferred counts, queue depths).
+        (per-tick wall times, admitted/deferred counts, queue depths,
+        and ``readbacks``: the device-to-host reads the call issued, one
+        packed read a tick and the flush's).
 
         ``telemetry``: an optional :class:`repro.telemetry.Telemetry`
         session.  When given, the queues carry the device-resident
@@ -237,16 +239,18 @@ class MultiTenantService:
         already pays for) and the host emits ``serve_window`` records
         every ``window`` ticks (0 disables windows), the per-tenant
         ``tenant`` table aggregated across streams, and a
-        ``serve_summary`` — all computed from values the loop already
-        transfers, so the telemetry session adds zero device syncs.
+        ``serve_summary`` (with ``readbacks``) — all computed from values
+        the loop already transfers, so the telemetry session adds zero
+        device syncs.
 
         Host spans on the profiler's clock (:func:`repro.telemetry.
         trace_span`; one context manager each when no profiler runs):
         ``serve.session`` over the call, holding ``serve.resolve``
         (request resolution), ``serve.setup`` (queues and keys), one
         ``serve.tick`` step per tick (its ``serve.stage``,
-        ``serve.dispatch``, ``serve.readback`` and ``serve.record``)
-        and ``serve.flush``.
+        ``serve.dispatch``, ``serve.readback`` — the tick's one read, of
+        its packed ``out["host"]`` — and ``serve.record``, which reads
+        nothing from the device) and ``serve.flush``.
         """
         if request_streams and isinstance(request_streams[0], Request):
             request_streams = [request_streams]
@@ -259,6 +263,7 @@ class MultiTenantService:
 
     def _serve_stream(self, request_streams, tick_k, ticks, seed,
                       telemetry, window) -> dict:
+        from repro.core.serve import host_layout, host_unpack  # see _tick_fns
         S = len(request_streams)
         names = self.env.registry.model_names
         # resolve every request up front into per-stream column arrays,
@@ -299,11 +304,12 @@ class MultiTenantService:
             keys = np.asarray(jax.random.split(jax.random.PRNGKey(seed),
                                                n_ticks))
         t_s = float(self.env.cfg.t_s_us)
+        layout = host_layout(self.env)
         head = np.zeros((S,), np.int64)    # first not-yet-admitted row
         completions: list[list[dict]] = [[] for _ in range(S)]
         tick_wall_us: list[float] = []
         depth_sum = 0
-        admitted = deferred = 0
+        admitted = deferred = readbacks = 0
         win = int(window) if telemetry is not None else 0
         w_first, w_adm, w_def, w_comp, w_depth = 0, 0, 0, 0, 0
         lane = np.arange(K)
@@ -321,16 +327,20 @@ class MultiTenantService:
                     adm = {k: np.take_along_axis(v, idx, axis=1)
                            for k, v in cols.items()}
                     adm["valid"] = valid
-                # tick_wall_us times the dispatch and the first two
-                # readbacks: the decision back on the host
+                # tick_wall_us times the dispatch and the tick's one
+                # read: the decision back on the host.  Every field the
+                # host needs comes in that read, packed as out["host"]
                 with trace_span("serve.dispatch"):
                     t0 = time.perf_counter()
                     queues, out = tick(self.params, queues, adm, keys[i])
+                    out["host"].copy_to_host_async()
                 with trace_span("serve.readback"):
-                    n_adm = np.asarray(out["n_admitted"])
-                    comp = np.asarray(out["completed"])
+                    buf = np.asarray(out["host"])
+                    readbacks += 1
                     tick_wall_us.append((time.perf_counter() - t0) * 1e6)
-                    depth = int(np.asarray(out["depth"]).sum())
+                    got = host_unpack(layout, buf)
+                n_adm, comp = got["n_admitted"], got["completed"]
+                depth = int(got["depth"].sum())
                 head += n_adm
                 admitted += int(n_adm.sum())
                 deferred += int((n_stage - n_adm).sum())
@@ -352,11 +362,12 @@ class MultiTenantService:
                             i + 1, 0, 0, 0, 0
                 if comp.any():
                     with trace_span("serve.record"):
-                        self._record(out, comp, completions)
+                        self._record(got, completions)
         with trace_span("serve.flush"):
             queues, fout = flush(queues)
             final = jax.tree.map(np.asarray, fout)
-            self._record(final, final["completed"], completions)
+            readbacks += 1
+            self._record(final, completions)
         metrics = []
         for s in range(S):
             m = dict(hits=float(final["hits"][s]),
@@ -385,7 +396,8 @@ class MultiTenantService:
         stats = dict(streams=S, ticks=n_ticks, tick_k=tick_k,
                      tick_wall_us=tick_wall_us, admitted=admitted,
                      deferred=deferred, unserved=unserved,
-                     mean_depth=depth_sum / max(n_ticks, 1))
+                     mean_depth=depth_sum / max(n_ticks, 1),
+                     readbacks=readbacks)
         if "tele_depth_hist" in final:
             # the device-accumulated block, read back at the flush
             stats["device_tele"] = dict(
@@ -408,27 +420,21 @@ class MultiTenantService:
                                sla_rate=row["sla_rate"])
             telemetry.emit("serve_summary",
                            sla_rate=aggregate["sla_rate"],
-                           counted=tot_c, ticks=n_ticks)
+                           counted=tot_c, ticks=n_ticks,
+                           readbacks=readbacks)
         return dict(metrics=metrics, aggregate=aggregate,
                     completions=completions, stats=stats)
 
     @staticmethod
-    def _record(out, comp, completions) -> None:
+    def _record(out, completions) -> None:
         """Append one tick's completed jobs to the per-stream logs (an LM
-        request's with its first-token time and the passes it had left)."""
-        comp = np.asarray(comp)
-        rid = np.asarray(out["rid"])
-        hit = np.asarray(out["hit"])
-        missed = np.asarray(out["missed"])
-        fin = np.asarray(out["finish_us"])
-        lm = "t_first" in out
-        if lm:
-            t_first = np.asarray(out["t_first"])
-            left = np.asarray(out["passes_left"])
-        for s, j in zip(*np.nonzero(comp)):
-            rec = dict(rid=int(rid[s, j]), hit=bool(hit[s, j]),
-                       missed=bool(missed[s, j]), finish_us=float(fin[s, j]))
-            if lm:
-                rec.update(t_first_us=float(t_first[s, j]),
-                           passes_left=int(left[s, j]))
-            completions[s].append(rec)
+        request's with its first-token time and the passes it had left).
+        ``out`` holds host arrays: nothing here reads the device."""
+        at = np.nonzero(out["completed"])
+        fields = dict(rid="rid", hit="hit", missed="missed",
+                      finish_us="finish_us")
+        if "t_first" in out:
+            fields.update(t_first_us="t_first", passes_left="passes_left")
+        cols = [out[k][at].tolist() for k in fields.values()]
+        for s, *vals in zip(at[0].tolist(), *cols):
+            completions[s].append(dict(zip(fields, vals)))

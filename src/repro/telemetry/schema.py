@@ -35,7 +35,8 @@ Record kinds
   serving): ``tenant``, ``jobs``; ``sla_rate`` is required but may be
   null (zero counted jobs — distinct from 0.0, all missed).
 - ``serve_summary`` — end-of-serving aggregate: ``sla_rate``,
-  ``counted``, ``ticks``.
+  ``counted``, ``ticks``, and ``readbacks`` (the device-to-host reads
+  the session issued: one packed read a tick, one at the flush).
 - ``span``        — a host-side timed section: ``name``, ``secs``.
 - ``note``        — free-form console context: ``msg``.
 - ``run_end``     — last record: optional summary payload.
@@ -62,7 +63,8 @@ SCHEMAS: dict[str, dict[str, tuple | type]] = {
                          completed=int, mean_depth=_NUM),
     "serve_episode": dict(episode=int, sla_rate=_NUM, energy_uj=_NUM),
     "tenant": dict(tenant=str, jobs=int, sla_rate=_OPT_NUM),
-    "serve_summary": dict(sla_rate=_NUM, counted=int, ticks=int),
+    "serve_summary": dict(sla_rate=_NUM, counted=int, ticks=int,
+                          readbacks=int),
     "span": dict(name=str, secs=_NUM),
     "note": dict(msg=str),
     "run_end": dict(),

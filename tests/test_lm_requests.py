@@ -4,7 +4,9 @@ one-pass (CNN) program left as it was.
 
 ``data/cnn_tick_parent.json.gz`` holds what the program traced before
 re-entering jobs existed, recorded with the same shapes as below: the
-light-set tick's StableHLO (telemetry off and on) and one episode of
+light-set tick's StableHLO (telemetry off and on; recorded again when
+the tick gained its packed host record ``out["host"]``, which adds the
+pack and that output and nothing else) and one episode of
 ``SchedulingEnv.episode`` (every leaf's bytes). A change that means to
 alter the one-pass program records it again.
 """
@@ -19,8 +21,8 @@ import pytest
 
 from repro.configs.registry import ARCHS, TENANT_ARCHS
 from repro.core import policy as P
-from repro.core.serve import make_serving_tick, queue_init_batch, \
-    specialist_act
+from repro.core.serve import (host_layout, host_unpack, make_serving_tick,
+                              queue_init_batch, specialist_act)
 from repro.serving import (LoadGenConfig, MultiTenantService, Request,
                            pack_admissions, queue_admit, queue_init,
                            resolve_request)
@@ -329,3 +331,65 @@ def test_lm_service_counts_both_limits_and_passes():
     assert 0.0 <= agg["sla_rate"] <= min(agg["ttft_rate"], agg["tpot_rate"])
     for m in res["metrics"]:
         assert m["hits"] <= min(m["ttft_hits"], m["tpot_hits"])
+
+
+# ---------------------------------------------------------------------------
+# the tick's packed host record carries the LM fields
+# ---------------------------------------------------------------------------
+def test_lm_host_record_unpacks_to_the_ticks_own_fields():
+    env = _lm_env()
+    S = 2
+    pcfg = P.PolicyConfig(feat_dim=env.feat_dim, act_dim=env.act_dim,
+                          hidden=8)
+    params = P.init_actor(jax.random.PRNGKey(0), pcfg)
+    tick = make_serving_tick(env, kind="specialist", pcfg=pcfg, streams=S)
+    layout = host_layout(env)
+    # stream 0: a request dropped at its 1 ms TTFT deadline and one with
+    # room; stream 1: one request of the other class
+    rows = [[(0, 0, 0.0, 1e3, 1e3, 4, 1e3), (1, 0, 0.0, 1e7, 1e7, 3, 1e6)],
+            [(2, 1, 0.0, 1e7, 1e7, 5, 1e6)]]
+    packs = [pack_admissions(r, 2) for r in rows]
+    adm0 = {k: np.stack([p[k] for p in packs]) for k in packs[0]}
+    queues = queue_init_batch(env, S)
+    seen = dict(completed=0, inf=0, finite=0, passes=0)
+    for i in range(6):
+        adm = adm0 if i == 0 else {**adm0,
+                                   "valid": np.zeros_like(adm0["valid"])}
+        if i == 1:
+            # request 1 has its first token: its t_first is finite
+            st = queues["state"]
+            queues = {**queues, "state": {
+                **st, "t_first": st["t_first"].at[0, 1].set(250.0)}}
+        queues, out = tick(params, queues, adm, jax.random.PRNGKey(i))
+        got = host_unpack(layout, np.asarray(out["host"]))
+        assert set(got) == {"n_admitted", "depth", "completed", "hit",
+                            "missed", "rid", "finish_us", "t_first",
+                            "passes_left"}
+        for k, v in got.items():
+            want = np.asarray(out[k])
+            assert (v.dtype, v.shape) == (want.dtype, want.shape), k
+            assert v.tobytes() == want.tobytes(), (i, k)
+        seen["completed"] += int(got["completed"].sum())
+        seen["inf"] += int((got["t_first"] == INF).sum()
+                           + (got["finish_us"] == INF).sum())
+        seen["finite"] += int((got["t_first"] < INF).sum())
+        seen["passes"] += int(got["passes_left"].sum())
+    assert all(seen.values()), seen
+
+
+def test_lm_serve_stream_equals_the_field_by_field_loop(serve_fieldwise):
+    svc = MultiTenantService(build_llm_registry("lm_dsv2lite"),
+                             policy="fcfs", env_cfg=LM_CFG)
+    lg = LoadGenConfig(scenario="burst", rate_scale=2.0, n_requests=4,
+                       out_median=8.0)
+    reqs = request_streams(svc.env, lg, 3, seed=7)
+    res = svc.serve_stream(reqs, tick_k=2, ticks=500, seed=1)
+    ref = serve_fieldwise(svc, reqs, tick_k=2, ticks=500, seed=1)
+    assert res["stats"]["readbacks"] == 501
+    done = [c for st in res["completions"] for c in st]
+    assert any(c["t_first_us"] < INF for c in done), done
+    stats = {k: v for k, v in res["stats"].items()
+             if k not in ("tick_wall_us", "readbacks")}
+    assert stats == ref["stats"]
+    for k in ("completions", "metrics", "aggregate"):
+        assert res[k] == ref[k], k

@@ -18,6 +18,8 @@ import pytest
 
 from repro.core import generalist as G
 from repro.core import policy as P
+from repro.core.serve import (host_layout, host_unpack, make_serving_tick,
+                              queue_init_batch)
 from repro.serving import (LoadGenConfig, MultiTenantService, Request,
                            pack_admissions, per_tenant_metrics, queue_admit,
                            queue_init, queue_retire, request_stream,
@@ -25,6 +27,7 @@ from repro.serving import (LoadGenConfig, MultiTenantService, Request,
 from repro.serving.loadgen import requests_to_trace
 from repro.sim.engine import INF, simulate_jax
 from repro.sim.env import EnvConfig, SchedulingEnv
+from repro.telemetry import ListSink, Telemetry
 from repro.workloads import build_registry
 
 CFG = EnvConfig(periods=10, max_rq=32, max_jobs=12)
@@ -289,3 +292,95 @@ def test_per_tenant_jobs_sum_to_counted_on_real_episode():
                            tick_k=CFG.max_jobs, seed=11)
     bm = out["metrics"][0]
     assert sum(t["jobs"] for t in bm["per_tenant"].values()) == bm["counted"]
+
+
+# ---------------------------------------------------------------------------
+# the tick's packed host record: one read a tick, the same answers
+# ---------------------------------------------------------------------------
+CNN_HOST_FIELDS = {"n_admitted", "depth", "completed", "hit", "missed",
+                   "rid", "finish_us"}
+
+
+def _staged_traces(env, streams: int, seed: int) -> dict:
+    """Every real job of one drawn trace per stream, staged at once."""
+    packs = []
+    for s in range(streams):
+        tr, _ = env.new_episode(np.random.default_rng(seed + s))
+        arr = np.asarray(tr["arrival"])
+        packs.append(pack_admissions(
+            [(j, int(tr["model"][j]), float(arr[j]),
+              float(tr["deadline"][j]), float(tr["q"][j]))
+             for j in range(arr.shape[0]) if arr[j] < INF / 2],
+            env.cfg.max_jobs))
+    return {k: np.stack([p[k] for p in packs]) for k in packs[0]}
+
+
+def test_host_record_unpacks_to_the_ticks_own_fields():
+    svc = MultiTenantService(build_registry("light"), policy="relmas",
+                             env_cfg=CFG)
+    env, S = svc.env, 3
+    tick = make_serving_tick(env, kind="specialist", pcfg=svc.pcfg,
+                             streams=S)
+    layout = host_layout(env)
+    queues = queue_init_batch(env, S)
+    adm0 = _staged_traces(env, S, seed=20)
+    seen = dict(completed=0, inf=0, finite=0)
+    for i in range(CFG.periods):
+        adm = adm0 if i == 0 else {**adm0,
+                                   "valid": np.zeros_like(adm0["valid"])}
+        queues, out = tick(svc.params, queues, adm, jax.random.PRNGKey(i))
+        got = host_unpack(layout, np.asarray(out["host"]))
+        assert set(got) == CNN_HOST_FIELDS
+        for k, v in got.items():
+            want = np.asarray(out[k])
+            assert (v.dtype, v.shape) == (want.dtype, want.shape), k
+            assert v.tobytes() == want.tobytes(), (i, k)
+        fin = got["finish_us"]
+        seen["completed"] += int(got["completed"].sum())
+        seen["inf"] += int((fin == INF).sum())
+        seen["finite"] += int((fin < INF).sum())
+    assert all(seen.values()), seen
+
+
+def _same_session(res: dict, ref: dict):
+    stats = {k: v for k, v in res["stats"].items()
+             if k not in ("tick_wall_us", "readbacks")}
+    assert stats == ref["stats"]
+    for k in ("completions", "metrics", "aggregate"):
+        assert res[k] == ref[k], k
+
+
+@pytest.mark.parametrize("policy,tick_k", [("relmas", CFG.max_jobs),
+                                           ("fcfs", 3), ("generalist", 4)])
+def test_serve_stream_equals_the_field_by_field_loop(serve_fieldwise, policy,
+                                                     tick_k):
+    """Three streams, backlogs deferred where ``tick_k`` is small: the
+    loop over the packed record serves what a loop reading each field
+    with its own transfer serves."""
+    if policy == "generalist":
+        svc = _generalist_service()
+    else:
+        svc = MultiTenantService(build_registry("light"), policy=policy,
+                                 env_cfg=CFG)
+    reqs = [trace_to_requests(svc.env, svc.env.new_episode(
+        np.random.default_rng(30 + s))[0]) for s in range(3)]
+    res = svc.serve_stream(reqs, tick_k=tick_k, seed=4)
+    ref = serve_fieldwise(svc, reqs, tick_k=tick_k, seed=4)
+    assert res["aggregate"]["completed"] > 0
+    _same_session(res, ref)
+
+
+@pytest.mark.parametrize("telemetry", [False, True])
+def test_serve_stream_reads_once_a_tick_and_once_at_the_flush(telemetry):
+    svc = MultiTenantService(build_registry("light"), policy="relmas",
+                             env_cfg=CFG)
+    reqs = [trace_to_requests(svc.env, svc.env.new_episode(
+        np.random.default_rng(s))[0]) for s in range(2)]
+    sink = ListSink()
+    res = svc.serve_stream(reqs, tick_k=CFG.max_jobs, ticks=7, seed=0,
+                           telemetry=Telemetry([sink]) if telemetry
+                           else None, window=3)
+    assert res["stats"]["readbacks"] == res["stats"]["ticks"] + 1 == 8
+    if telemetry:
+        (summ,) = [r for r in sink.records if r["kind"] == "serve_summary"]
+        assert summ["readbacks"] == 8
