@@ -2,14 +2,13 @@
 
   python -m benchmarks.run            # quick CI-sized pass (default)
   python -m benchmarks.run --full     # paper-sized episode counts
-  python -m benchmarks.run --only fig3,roofline
+  python -m benchmarks.run --only fig3,fig4
   python -m benchmarks.run --only sweep     # scenario x policy x bw grid
   python -m benchmarks.run --only transfer  # cross-fleet transfer matrix
 
 Output: CSV-ish lines per benchmark (stable prefixes: fig3, fig4, fig5,
 table1, table2 — both emitted by the table1 entry — policy_latency,
-straggler, rooflinesummary, sweep) + a final JSON summary line.  The roofline entry renders the dry-run sweep
-(runs/dryrun/all.jsonl) produced by launch/dryrun.py.
+straggler, sweep) + a final JSON summary line.
 
 Machine-readable perf-trajectory artifacts (for cross-PR regression
 tracking; schemas in docs/BENCHMARKS.md): ``benchmarks/sweep.py``
@@ -43,7 +42,7 @@ def main(argv=None):
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,
                     help="comma list: fig3,fig4,fig5,table1,policy,"
-                         "serving,straggler,roofline,sweep,transfer")
+                         "serving,straggler,sweep,transfer")
     ap.add_argument("--no-magma", action="store_true",
                     help="skip the GA baseline (slowest bench)")
     ap.add_argument("--fleets", default=None,
@@ -100,9 +99,6 @@ def main(argv=None):
     if want("straggler"):
         from benchmarks import straggler_bench
         results["straggler"] = straggler_bench.run(quick=quick)["drop"]
-    if want("roofline"):
-        from benchmarks import roofline_report
-        results["roofline"] = roofline_report.run()
     results["wall_s"] = round(time.time() - t0, 1)
     print("benchsummary," + json.dumps(results, default=str), flush=True)
     import os

@@ -4,9 +4,9 @@ Design points for large-scale runs:
 - **Atomicity**: write to ``<dir>/tmp.<step>`` then ``os.replace`` — a
   crash mid-write never corrupts the latest checkpoint (restart safety).
 - **Mesh-agnostic storage**: arrays are saved as host NumPy, keyed by
-  their pytree key-path, so a checkpoint written on a 256-chip mesh
-  restores onto 512 chips (or 1 CPU) — re-sharding happens at
-  ``device_put`` time via ``runtime.elastic`` (elastic scaling).
+  their pytree key-path, so a checkpoint written on one mesh restores
+  onto another (or 1 CPU) — placement happens at ``device_put`` time
+  in the caller.
 - **Retention**: ``CheckpointManager`` keeps the last K checkpoints and
   survives preexisting/partial directories.
 
@@ -69,7 +69,7 @@ def restore_checkpoint(directory: str, like, step: int | None = None):
     """Restore into the structure of ``like``. Returns (tree, step, meta).
 
     ``like`` may live on any mesh/size — only the *structure* and shapes
-    are used; placement is the caller's concern (see runtime.elastic).
+    are used; placement is the caller's concern.
     """
     if step is None:
         step = latest_step(directory)

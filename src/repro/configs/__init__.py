@@ -1,7 +1,5 @@
-"""Architecture configs (``--arch <id>``): the 10 assigned architectures
-plus the paper's own RELMAS scheduler config."""
-from repro.configs.base import ArchConfig, SHAPES, ShapeSpec
-from repro.configs.registry import ARCHS, get_arch, list_archs
+"""LM architecture descriptors, served as scheduler tenants."""
+from repro.configs.base import ArchConfig
+from repro.configs.registry import TENANT_ARCHS
 
-__all__ = ["ArchConfig", "SHAPES", "ShapeSpec", "ARCHS", "get_arch",
-           "list_archs"]
+__all__ = ["ArchConfig", "TENANT_ARCHS"]

@@ -8,11 +8,4 @@ FULL = ArchConfig(
     name="deepseek-7b", family="dense",
     n_layers=30, d_model=4096, n_heads=32, n_kv=32, head_dim=128,
     d_ff=11008, vocab=102400,
-    source="[arXiv:2401.02954; hf]",
-)
-
-SMOKE = ArchConfig(
-    name="deepseek-smoke", family="dense",
-    n_layers=2, d_model=64, n_heads=4, n_kv=4, head_dim=16,
-    d_ff=128, vocab=512, param_dtype="float32", remat=False,
 )

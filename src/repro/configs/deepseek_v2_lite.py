@@ -6,9 +6,7 @@ layers 1-26 MoE with 64 routed experts (top-6) and 2 shared experts of
 width 1408; vocab=102400
 (https://huggingface.co/deepseek-ai/DeepSeek-V2-Lite/blob/main/config.json).
 
-A scheduler tenant only: ``repro.models`` has no MLA block, so this
-architecture is served through ``workloads.llm_zoo``'s layer tables
-and is not in :data:`repro.configs.registry.ARCHS`.
+Its latent attention is costed by ``workloads.llm_zoo._mla_layer``.
 """
 from repro.configs.base import ArchConfig
 
@@ -19,5 +17,4 @@ FULL = ArchConfig(
     n_experts=64, top_k=6, moe_d_ff=1408, n_shared_experts=2,
     first_k_dense=1, kv_lora_rank=512, qk_nope_head_dim=128,
     qk_rope_head_dim=64, v_head_dim=128,
-    source="[arXiv:2405.04434; hf]",
 )

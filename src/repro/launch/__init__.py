@@ -1,1 +1,2 @@
-"""Launchers: mesh construction, AOT dry-run, training & serving drivers."""
+"""Launchers: the RELMAS training and serving drivers and the compile
+cache they share."""

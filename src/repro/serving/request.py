@@ -1,4 +1,4 @@
-"""Inference request objects + synthetic multi-tenant request streams.
+"""Inference request objects and their admission into the device queue.
 
 Admission validation lives here: :func:`resolve_request` is the single
 place a host-side :class:`Request` becomes a device-queue row, and it
@@ -18,8 +18,6 @@ from __future__ import annotations
 import dataclasses
 from typing import NamedTuple
 
-import numpy as np
-
 
 @dataclasses.dataclass
 class Request:
@@ -31,15 +29,12 @@ class Request:
     # deadline - arrival (trace replays pass the trace's exact q so the
     # batched path stays bit-identical to the reference)
     q_us: float | None = None
-    prompt: np.ndarray | None = None    # token ids (data-plane path)
-    max_new: int = 16
     # whole LM requests: output tokens (passes) and the TPOT limit
     n_out: int = 1
     tpot_us: float | None = None
     # filled by the service
     finish_us: float = float("inf")
     hit: bool = False
-    tokens_out: list = dataclasses.field(default_factory=list)
 
 
 class QueueRow(NamedTuple):
@@ -91,24 +86,3 @@ def resolve_request(req: Request, model_names) -> QueueRow:
     return QueueRow(mid, float(req.arrival_us), float(req.deadline_us),
                     float(q), n_out, float(tpot))
 
-
-def synth_requests(tenants: list[str], *, n: int, horizon_us: float,
-                   qos_budget_us: dict[str, float], seed: int = 0,
-                   pareto_shape: float = 2.0, vocab: int = 256,
-                   prompt_len: int = 8, max_new: int = 16) -> list[Request]:
-    """Pareto inter-arrivals (paper Sec. 5), uniform tenant mix."""
-    rng = np.random.default_rng(seed)
-    mean_ia = horizon_us / max(n, 1)
-    xm = mean_ia * (pareto_shape - 1.0) / pareto_shape
-    inter = xm * (1.0 + rng.pareto(pareto_shape, size=n))
-    arrivals = np.cumsum(np.minimum(inter, 20 * mean_ia))
-    arrivals[0] = 0.0
-    out = []
-    for i, t_us in enumerate(arrivals):
-        tenant = tenants[int(rng.integers(len(tenants)))]
-        out.append(Request(
-            rid=i, tenant=tenant, arrival_us=float(t_us),
-            deadline_us=float(t_us + qos_budget_us[tenant]),
-            prompt=rng.integers(0, vocab, size=prompt_len).astype(np.int32),
-            max_new=max_new))
-    return out

@@ -42,12 +42,12 @@ Event semantics (documented in ARCHITECTURE.md "Time-varying fleets"):
   sub-jobs demand ``mag``x the shared bus bandwidth per unit of work,
   raising contention for everyone overlapping them.
 
-The event draws themselves live with the runtime machinery they model:
-``runtime/fault.failure_schedule``, ``runtime/straggler
-.slowdown_schedule`` / ``throttle_schedule``, ``runtime/elastic
-.join_schedule``.  This module assembles them into the traced
-representation (NumPy host path for eval/benchmarks, ``jax.random``
-twin for the fused training rounds).
+One generator per event class draws the events
+(:func:`failure_schedule`, :func:`join_schedule`,
+:func:`slowdown_schedule`, :func:`throttle_schedule`);
+:func:`churn_events` assembles them into the traced representation
+(NumPy host path for eval/benchmarks, ``jax.random`` twin for the fused
+training rounds).
 
 An all-no-op schedule (:func:`no_op_schedule`, or ``compile_schedule``
 of all-``EV_NONE`` events) is the **bit-exact identity**: every churn
@@ -62,10 +62,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro.runtime.elastic import join_schedule
-from repro.runtime.fault import failure_schedule
-from repro.runtime.straggler import slowdown_schedule, throttle_schedule
 
 # event codes (the `code` column of the fixed-shape event arrays)
 EV_NONE, EV_FAIL, EV_JOIN, EV_THROTTLE, EV_SLOWDOWN = 0, 1, 2, 3, 4
@@ -121,14 +117,86 @@ def no_op_events(max_events: int = 4) -> dict[str, np.ndarray]:
                 mag=np.ones((max_events,), np.float32))
 
 
+def _draw(rng: np.random.Generator, n: int, periods: int, num_sas: int,
+          window: tuple[float, float]) -> tuple[np.ndarray, np.ndarray]:
+    """``n`` events on distinct SAs at uniform periods inside
+    ``window``: (period, sa) int32 arrays."""
+    lo = int(window[0] * periods)
+    hi = max(lo + 1, int(window[1] * periods))
+    p = rng.integers(lo, hi, size=n)
+    sa = rng.choice(num_sas, size=n, replace=False)
+    return p.astype(np.int32), sa.astype(np.int32)
+
+
+def failure_schedule(rng: np.random.Generator, *, periods: int,
+                     num_sas: int, n: int = 1,
+                     window: tuple[float, float] = (0.25, 0.75)
+                     ) -> tuple[np.ndarray, np.ndarray]:
+    """Draw ``n`` fail-stop events: (period, sa) int32 arrays.
+
+    Each event marks one SA as failed from that period onward.  Events
+    land uniformly inside ``window`` (fractions of the episode) and
+    target *distinct* SAs; ``n`` is clamped to ``num_sas - 1`` so at
+    least one SA survives — a fleet with zero valid SAs has no
+    meaningful schedule.
+    """
+    return _draw(rng, max(0, min(int(n), num_sas - 1)), periods, num_sas,
+                 window)
+
+
+def join_schedule(rng: np.random.Generator, *, periods: int,
+                  num_sas: int, n: int = 1,
+                  window: tuple[float, float] = (0.25, 0.75)
+                  ) -> tuple[np.ndarray, np.ndarray]:
+    """Draw ``n`` elastic-join events: (period, sa) int32 arrays.
+
+    Capacity appears mid-run: a join target is *absent* (invalid) from
+    period 0 until its event period, then flips valid.  Distinct SAs,
+    uniform periods inside ``window``.
+    """
+    return _draw(rng, max(0, min(int(n), num_sas)), periods, num_sas,
+                 window)
+
+
+def slowdown_schedule(rng: np.random.Generator, *, periods: int,
+                      num_sas: int, n: int = 1,
+                      window: tuple[float, float] = (0.25, 0.75),
+                      magnitude: float = 4.0
+                      ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Draw ``n`` compute-straggler events: (period, sa, lat_mult).
+
+    From each event's period onward the target SA executes every layer
+    ``magnitude``x slower, and its advertised busy-times scale with it,
+    so deadline-aware policies can route around it.  Distinct SAs,
+    uniform periods inside ``window``.
+    """
+    p, sa = join_schedule(rng, periods=periods, num_sas=num_sas, n=n,
+                          window=window)
+    return p, sa, np.full(len(p), magnitude, np.float32)
+
+
+def throttle_schedule(rng: np.random.Generator, *, periods: int,
+                      num_sas: int, n: int = 1,
+                      window: tuple[float, float] = (0.25, 0.75),
+                      magnitude: float = 4.0
+                      ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Draw ``n`` memory-path throttle events: (period, sa, bw_mult).
+
+    A throttled SA's DRAM link degrades: its sub-jobs demand
+    ``magnitude``x the bus bandwidth per unit of work (MoCA-style
+    contention pressure), so overlapping SJs fleet-wide see more stall
+    cycles.  Same draw scheme as :func:`slowdown_schedule`.
+    """
+    return slowdown_schedule(rng, periods=periods, num_sas=num_sas, n=n,
+                             window=window, magnitude=magnitude)
+
+
 def churn_events(cfg: ChurnConfig, periods: int, num_sas: int,
                  rng: np.random.Generator) -> dict[str, np.ndarray]:
     """Host-side (NumPy) event draw for one episode.
 
-    Dispatches each event class to its runtime generator
-    (fault/straggler/elastic), so the runtime modules own the draw
-    semantics and this module owns the traced representation.  Fixed
-    shape ``E = cfg.max_events`` regardless of scenario.
+    Dispatches each event class to its generator above.  Fixed shape
+    ``E = cfg.max_events`` regardless of scenario.
     """
     ev = no_op_events(cfg.max_events)
     plan = _event_plan(cfg)

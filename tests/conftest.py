@@ -1,8 +1,8 @@
 """Shared pytest config.
 
 NOTE: no XLA_FLAGS here — smoke tests and benches must see ONE device;
-only launch/dryrun.py (and subprocess tests driving it) force the
-512/8-device placeholder fleet.
+only the subprocess tests of ``test_train_sharded.py`` force a
+2-device CPU mesh, in their own environment.
 
 The persistent compile cache is off for the whole suite: the entry
 points that tests start as subprocesses (rl_train, serve) would turn it
@@ -19,7 +19,7 @@ os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 def pytest_configure(config):
     config.addinivalue_line("markers",
-                            "slow: long-running (subprocess dry-runs, e2e)")
+                            "slow: long-running (subprocess drivers, e2e)")
 
 
 def _serve_fieldwise(svc, request_streams, *, tick_k: int,
@@ -124,3 +124,19 @@ def _serve_fieldwise(svc, request_streams, *, tick_k: int,
 def serve_fieldwise():
     """The field-by-field reference loop of the batched serving path."""
     return _serve_fieldwise
+
+
+PARITY_KEYS = ("hits", "counted", "arrived", "sla_rate", "energy_uj")
+
+
+def _assert_parity(ref: dict, m: dict):
+    for k in PARITY_KEYS:
+        assert ref[k] == m[k], f"{k}: host {ref[k]} != batched {m[k]}"
+    assert ref["per_tenant"] == m["per_tenant"]
+
+
+@pytest.fixture
+def assert_parity():
+    """Asserts that a host-loop episode (``serve_trace_host``) and one
+    stream of ``serve_stream`` retired the same numbers, bit for bit."""
+    return _assert_parity

@@ -37,7 +37,9 @@ from repro.sim.churn import (CHURN_SCENARIOS, EV_FAIL, EV_JOIN, EV_NONE,
                              churn_events, churn_events_jax, churn_preset,
                              churn_schedule, churn_schedules,
                              churn_schedules_jax, compile_schedule,
-                             no_op_events, no_op_schedule)
+                             failure_schedule, join_schedule,
+                             no_op_events, no_op_schedule,
+                             slowdown_schedule, throttle_schedule)
 from repro.sim.env import EnvConfig, SchedulingEnv
 from repro.workloads import build_registry
 
@@ -371,3 +373,37 @@ def test_churn_schedules_jax_shapes_and_masked_targets():
     assert np.asarray(scheds["valid"]).all()         # slowdown: no fails
     lat = np.asarray(scheds["lat_mult"])
     assert lat.max() == 2.0 and lat.min() == 1.0
+
+
+# ------------------------------------------- churn event generators
+def test_failure_schedule_window_distinct_and_clamped():
+    rng = np.random.default_rng(0)
+    p, sa = failure_schedule(rng, periods=20, num_sas=4, n=10)
+    assert len(p) == len(sa) == 3           # clamped: one SA survives
+    assert p.dtype == np.int32 and sa.dtype == np.int32
+    assert (p >= 5).all() and (p < 15).all()    # window (0.25, 0.75)
+    assert len(set(sa.tolist())) == 3           # distinct targets
+    p2, sa2 = failure_schedule(np.random.default_rng(0), periods=20,
+                               num_sas=4, n=10)
+    assert np.array_equal(p, p2) and np.array_equal(sa, sa2)
+
+
+def test_join_schedule_shapes_and_window():
+    p, sa = join_schedule(np.random.default_rng(1), periods=16, num_sas=6,
+                          n=2, window=(0.5, 1.0))
+    assert len(p) == 2
+    assert (p >= 8).all() and (p < 16).all()
+    assert len(set(sa.tolist())) == 2
+
+
+def test_degradation_schedules_magnitude():
+    for fn in (slowdown_schedule, throttle_schedule):
+        p, sa, mag = fn(np.random.default_rng(2), periods=12, num_sas=5,
+                        n=3, magnitude=6.0)
+        assert len(p) == len(sa) == len(mag) == 3
+        assert (mag == np.float32(6.0)).all()
+        assert len(set(sa.tolist())) == 3
+    # n clamps to the fleet width (degradation may hit every SA)
+    p, sa, _ = slowdown_schedule(np.random.default_rng(3), periods=12,
+                                 num_sas=2, n=9)
+    assert len(sa) == 2

@@ -1,15 +1,11 @@
 """Checkpoint tests: atomicity, retention, restore-into-structure."""
 import os
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.ckpt import CheckpointManager, restore_checkpoint, save_checkpoint
-from repro.runtime.elastic import device_put_like
-from repro.models import sharding as shd
-from repro.launch.mesh import make_host_mesh
 
 
 def _tree():
@@ -40,16 +36,3 @@ def test_shape_mismatch_is_loud(tmp_path):
     with pytest.raises(ValueError):
         restore_checkpoint(str(tmp_path), bad)
 
-
-def test_elastic_restore_onto_host_mesh(tmp_path):
-    """Checkpoint -> host numpy -> device_put under mesh rules (the same
-    path reshards onto 256/512 chips; multi-device variant covered by
-    the subprocess dry-run test)."""
-    t = _tree()
-    save_checkpoint(str(tmp_path), 1, t)
-    mesh = make_host_mesh()
-    rules = shd.make_rules(False)
-    host, step, _ = restore_checkpoint(str(tmp_path), t)
-    placed = device_put_like(host, mesh, rules)
-    np.testing.assert_array_equal(np.asarray(placed["a"]), t["a"])
-    assert all(x.sharding is not None for x in jax.tree.leaves(placed))

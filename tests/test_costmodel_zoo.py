@@ -2,7 +2,7 @@
 import numpy as np
 import pytest
 
-from repro.configs.registry import ARCHS
+from repro.configs.registry import TENANT_ARCHS
 from repro.costmodel import (DEFAULT_MAS, EYERISS_LARGE, SIMBA_LARGE,
                              SIMBA_SMALL, conv2d, fc, layer_cost)
 from repro.costmodel.accelerators import DATACENTER_MAS
@@ -55,9 +55,9 @@ def test_cnn_zoo_tables():
     assert ml["keyword_spotting"] < ml["squeezenet"]
 
 
-@pytest.mark.parametrize("arch", list(ARCHS))
+@pytest.mark.parametrize("arch", list(TENANT_ARCHS))
 def test_llm_layerization_all_archs(arch):
-    cfg = ARCHS[arch]
+    cfg = TENANT_ARCHS[arch]
     for phase in ("prefill", "decode"):
         ls = llm_layer_specs(cfg, phase=phase, seq=64, ctx=512)
         expected = cfg.n_layers + 2 + (cfg.enc_layers

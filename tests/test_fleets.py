@@ -13,6 +13,7 @@ from repro.costmodel.fleets import fleet_names
 from repro.sim.engine import simulate_jax, simulate_np
 from repro.sim.env import EnvConfig, SchedulingEnv
 from repro.workloads import build_registry
+from repro.workloads.cnn_zoo import WORKLOADS
 
 
 # ---------------------------------------------------------------------------
@@ -44,15 +45,16 @@ def test_dataflow_mixes():
 # ---------------------------------------------------------------------------
 # registration phase per fleet
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("fleet", ["4simba_4eyeriss", "8eyeriss",
-                                   "2simba_2eyeriss", "big_little"])
-def test_registry_tables_follow_fleet_shape(fleet):
+@pytest.mark.parametrize("workload", sorted(WORKLOADS))
+@pytest.mark.parametrize("fleet", fleet_names())
+def test_registry_tables_follow_fleet_shape(fleet, workload):
     fl = get_fleet(fleet)
-    d = build_registry("light", mas=fleet).dense()
-    assert d["num_sas"] == fl.num_sas
-    assert d["lat"].shape == (3, d["lmax"], fl.num_sas)
+    d = build_registry(workload, mas=fleet).dense()
+    n = len(WORKLOADS[workload])
+    assert d["num_sas"] == fl.num_sas and d["num_models"] == n
+    assert d["lat"].shape == (n, d["lmax"], fl.num_sas)
     assert d["bw"].shape == d["lat"].shape == d["en"].shape
-    for i in range(3):  # real layers characterize positive on every SA
+    for i in range(n):  # real layers characterize positive on every SA
         L = d["n_layers"][i]
         assert (d["lat"][i, :L] > 0).all() and (d["en"][i, :L] > 0).all()
     assert (d["min_lat"] > 0).all()

@@ -47,121 +47,3 @@ def test_lstm_cell_matches_policy_cell():
     h2r, c2r = policy_cell(x, h, c, wx, wh, b)
     np.testing.assert_allclose(h2p, h2r, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(c2p, c2r, atol=1e-5, rtol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# flash_attention
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("B,Hq,Hkv,S,D,causal,window", [
-    (2, 4, 4, 256, 64, True, 0),
-    (1, 8, 2, 256, 64, True, 0),      # GQA
-    (2, 4, 4, 256, 64, True, 128),    # sliding window
-    (1, 2, 2, 384, 128, True, 0),
-    (1, 2, 1, 200, 64, True, 0),      # padding path
-    (1, 2, 2, 256, 64, False, 0),     # encoder (non-causal)
-])
-def test_flash_attention(B, Hq, Hkv, S, D, causal, window):
-    from repro.kernels.flash_attention import ops, ref
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, Hq, S, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, Hkv, S, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, Hkv, S, D), jnp.float32)
-    o_k = ops.flash_attention(q, k, v, causal=causal, window=window)
-    o_n = ref.attention_naive(q, k, v, causal=causal, window=window)
-    o_c = ref.attention_chunked(q, k, v, causal=causal, window=window,
-                                block_q=128)
-    np.testing.assert_allclose(o_k, o_n, atol=2e-5, rtol=1e-4)
-    np.testing.assert_allclose(o_c, o_n, atol=2e-5, rtol=1e-4)
-
-
-def test_flash_attention_bf16():
-    from repro.kernels.flash_attention import ops, ref
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (1, 2, 256, 64), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (1, 2, 256, 64), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (1, 2, 256, 64), jnp.bfloat16)
-    o_k = ops.flash_attention(q, k, v)
-    o_n = ref.attention_naive(q.astype(jnp.float32), k.astype(jnp.float32),
-                              v.astype(jnp.float32))
-    np.testing.assert_allclose(np.asarray(o_k, np.float32), o_n,
-                               atol=3e-2, rtol=3e-2)
-
-
-# ---------------------------------------------------------------------------
-# decode_gqa
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("B,Hq,Hkv,S,D,bk", [
-    (2, 4, 4, 256, 64, 128),
-    (4, 8, 2, 1024, 64, 512),
-    (1, 4, 1, 300, 128, 256),   # padding path
-])
-def test_decode_attention(B, Hq, Hkv, S, D, bk):
-    from repro.kernels.decode_gqa import ops, ref
-    ks = jax.random.split(KEY, 4)
-    q = jax.random.normal(ks[0], (B, Hq, 1, D))
-    k = jax.random.normal(ks[1], (B, Hkv, S, D))
-    v = jax.random.normal(ks[2], (B, Hkv, S, D))
-    length = jax.random.randint(ks[3], (B,), 1, S + 1)
-    o_k = ops.decode_attention(q, k, v, length, block_k=bk)
-    o_r = ref.decode_attention_ref(q, k, v, length)
-    np.testing.assert_allclose(o_k, o_r, atol=2e-5, rtol=1e-4)
-
-
-# ---------------------------------------------------------------------------
-# ssd_chunk (Mamba-2)
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("B,T,H,P,N,chunk", [
-    (2, 64, 4, 16, 32, 16),
-    (1, 128, 8, 64, 128, 64),
-    (2, 100, 2, 32, 64, 32),    # padding path
-])
-def test_ssd_forward(B, T, H, P, N, chunk):
-    from repro.kernels.ssd_chunk import ops, ref
-    ks = jax.random.split(KEY, 5)
-    x = jax.random.normal(ks[0], (B, T, H, P)) * 0.5
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, T, H))) * 0.5
-    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
-    Bm = jax.random.normal(ks[3], (B, T, N)) * 0.3
-    Cm = jax.random.normal(ks[4], (B, T, N)) * 0.3
-    y_scan, S_scan = ref.ssd_scan_ref(x, dt, A, Bm, Cm)
-    y_ops, S_ops = ops.ssd_forward(x, dt, A, Bm, Cm, chunk=chunk)
-    np.testing.assert_allclose(y_ops, y_scan, atol=5e-4, rtol=1e-3)
-    np.testing.assert_allclose(S_ops, S_scan, atol=5e-4, rtol=1e-3)
-
-
-def test_ssd_decode_matches_scan():
-    from repro.kernels.ssd_chunk import ref
-    B, T, H, P, N = 2, 8, 4, 16, 32
-    ks = jax.random.split(KEY, 5)
-    x = jax.random.normal(ks[0], (B, T, H, P)) * 0.5
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, T, H))) * 0.5
-    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
-    Bm = jax.random.normal(ks[3], (B, T, N)) * 0.3
-    Cm = jax.random.normal(ks[4], (B, T, N)) * 0.3
-    y_scan, _ = ref.ssd_scan_ref(x, dt, A, Bm, Cm)
-    state = jnp.zeros((B, H, N, P))
-    for t in range(T):
-        state, y_t = ref.ssd_decode_step(state, x[:, t], dt[:, t], A,
-                                         Bm[:, t], Cm[:, t])
-        np.testing.assert_allclose(y_t, y_scan[:, t], atol=1e-4, rtol=1e-3)
-
-
-def test_ssd_state_carry_across_calls():
-    """Chunked prefill then stateful continuation == one long prefill."""
-    from repro.kernels.ssd_chunk import ops, ref
-    B, T, H, P, N = 1, 64, 2, 16, 32
-    ks = jax.random.split(KEY, 5)
-    x = jax.random.normal(ks[0], (B, T, H, P)) * 0.5
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, T, H))) * 0.5
-    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
-    Bm = jax.random.normal(ks[3], (B, T, N)) * 0.3
-    Cm = jax.random.normal(ks[4], (B, T, N)) * 0.3
-    y_full, S_full = ops.ssd_forward(x, dt, A, Bm, Cm, chunk=16)
-    h = T // 2
-    y1, S1 = ops.ssd_forward(x[:, :h], dt[:, :h], A, Bm[:, :h], Cm[:, :h],
-                             chunk=16)
-    y2, S2 = ops.ssd_forward(x[:, h:], dt[:, h:], A, Bm[:, h:], Cm[:, h:],
-                             init_state=S1, chunk=16)
-    np.testing.assert_allclose(jnp.concatenate([y1, y2], axis=1), y_full,
-                               atol=5e-4, rtol=1e-3)
-    np.testing.assert_allclose(S2, S_full, atol=5e-4, rtol=1e-3)

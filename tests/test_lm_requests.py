@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.registry import ARCHS, TENANT_ARCHS
+from repro.configs.registry import TENANT_ARCHS
 from repro.core import policy as P
 from repro.core.serve import (host_layout, host_unpack, make_serving_tick,
                               queue_init_batch, specialist_act)
@@ -36,6 +36,7 @@ GOLDEN = os.path.join(os.path.dirname(__file__), "data",
                       "cnn_tick_parent.json.gz")
 CNN_CFG = EnvConfig(periods=6, max_rq=24, max_jobs=8)
 LM_CFG = EnvConfig(periods=60, max_rq=32, max_jobs=8)
+LM_SETS = ["lm_dsv2lite", "lm_light", "lm_heavy", "lm_mixed", "lm_all"]
 
 
 @pytest.fixture(scope="module")
@@ -150,7 +151,7 @@ def test_moe_ffn_uses_expert_width_and_shared_experts():
     assert dec[1].w_bytes == attn_w + 2 * 3 * d * dsv2.d_ff  # layer 0 dense
     assert dec[2].w_bytes == attn_w + moe_w
     assert pre[2].w_bytes == attn_w + 2 * (3 * d * w * (2 + 64) + d * 64)
-    olmoe = ARCHS["olmoe-1b-7b"]
+    olmoe = TENANT_ARCHS["olmoe-1b-7b"]
     o = llm_layer_specs(olmoe, phase="decode", ctx=128)[1]
     dense = llm_layer_specs(olmoe.__class__(**{
         **olmoe.__dict__, "n_experts": 0, "top_k": 0,
@@ -306,16 +307,21 @@ def test_drops_use_the_current_limit():
     assert bool(env.mark_drops(st, tr, 501.0)["missed"][0])
 
 
-def test_lm_service_counts_both_limits_and_passes():
+# lm_all's seed-5 streams hold no request done before tick ~2,600 (its
+# llama3-405b prefill alone takes ~7,900 ticks): a longer session
+@pytest.mark.parametrize("workload,ticks", [
+    *((w, 700) for w in LM_SETS[:-1]), ("lm_all", 3000)])
+def test_lm_service_counts_both_limits_and_passes(workload, ticks):
     env_cfg = EnvConfig(periods=60, max_rq=32, max_jobs=8)
-    svc = MultiTenantService(build_llm_registry("lm_dsv2lite"),
+    svc = MultiTenantService(build_llm_registry(workload),
                              policy="fcfs", env_cfg=env_cfg)
     lg = LoadGenConfig(scenario="burst", rate_scale=2.0, n_requests=3,
                        out_median=8.0)
     reqs = request_streams(svc.env, lg, 2, seed=5)
     assert all(r.n_out >= 8 and r.tpot_us > 0 for s in reqs for r in s)
     from repro.telemetry import ListSink, Telemetry
-    res = svc.serve_stream(reqs, ticks=700, telemetry=Telemetry([ListSink()]))
+    res = svc.serve_stream(reqs, ticks=ticks,
+                           telemetry=Telemetry([ListSink()]))
     done = [c for s in res["completions"] for c in s if not c["missed"]]
     assert done, "no request finished inside the session"
     n_out = {(s, r.rid): r.n_out for s, st in enumerate(reqs) for r in st}
@@ -377,15 +383,17 @@ def test_lm_host_record_unpacks_to_the_ticks_own_fields():
     assert all(seen.values()), seen
 
 
-def test_lm_serve_stream_equals_the_field_by_field_loop(serve_fieldwise):
-    svc = MultiTenantService(build_llm_registry("lm_dsv2lite"),
+@pytest.mark.parametrize("workload,ticks", [(w, 500) for w in LM_SETS])
+def test_lm_serve_stream_equals_the_field_by_field_loop(serve_fieldwise,
+                                                        workload, ticks):
+    svc = MultiTenantService(build_llm_registry(workload),
                              policy="fcfs", env_cfg=LM_CFG)
     lg = LoadGenConfig(scenario="burst", rate_scale=2.0, n_requests=4,
                        out_median=8.0)
     reqs = request_streams(svc.env, lg, 3, seed=7)
-    res = svc.serve_stream(reqs, tick_k=2, ticks=500, seed=1)
-    ref = serve_fieldwise(svc, reqs, tick_k=2, ticks=500, seed=1)
-    assert res["stats"]["readbacks"] == 501
+    res = svc.serve_stream(reqs, tick_k=2, ticks=ticks, seed=1)
+    ref = serve_fieldwise(svc, reqs, tick_k=2, ticks=ticks, seed=1)
+    assert res["stats"]["readbacks"] == ticks + 1
     done = [c for st in res["completions"] for c in st]
     assert any(c["t_first_us"] < INF for c in done), done
     stats = {k: v for k, v in res["stats"].items()

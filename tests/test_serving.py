@@ -1,67 +1,7 @@
-"""Serving plane: continuous batcher (real model) + scheduling service."""
-import jax
-import jax.numpy as jnp
-import numpy as np
-import pytest
-
-from repro.configs.registry import get_arch
-from repro.models.model import build_model
-from repro.serving import (ContinuousBatcher, MultiTenantService, Request,
-                           synth_requests)
+"""Serving plane: the scheduling service over CNN and LM tenants."""
+from repro.serving import MultiTenantService
 from repro.sim.env import EnvConfig
 from repro.workloads import build_registry, build_llm_registry
-
-
-@pytest.fixture(scope="module")
-def small_lm():
-    cfg = get_arch("internlm2-1.8b", smoke=True)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    return model, params
-
-
-def test_continuous_batcher_serves_all(small_lm):
-    model, params = small_lm
-    bat = ContinuousBatcher(model, params, n_slots=2, smax=64)
-    reqs = synth_requests(["internlm2-1.8b"], n=5, horizon_us=100.0,
-                          qos_budget_us={"internlm2-1.8b": 1e9},
-                          vocab=model.cfg.vocab, prompt_len=4, max_new=6)
-    pending = list(reqs)
-    done = []
-    for _ in range(200):
-        while pending and bat.has_free_slot():
-            bat.add(pending.pop(0))
-        done += bat.step()
-        if not pending and bat.active() == 0:
-            break
-    assert len(done) == 5
-    for r in done:
-        assert len(r.tokens_out) == 6
-        assert all(0 <= t < model.cfg.vocab_padded for t in r.tokens_out)
-
-
-def test_batcher_slot_reuse_isolated(small_lm):
-    """Slot reuse must not leak cache state across requests: a request
-    decoded alone equals the same request decoded after slot churn."""
-    model, params = small_lm
-    prompt = np.arange(4, dtype=np.int32)
-
-    def run(batcher):
-        r = Request(rid=0, tenant="x", arrival_us=0, deadline_us=1e9,
-                    prompt=prompt, max_new=4)
-        batcher.add(r)
-        while batcher.active():
-            batcher.step()
-        return r.tokens_out
-
-    solo = run(ContinuousBatcher(model, params, n_slots=2, smax=64))
-    churn = ContinuousBatcher(model, params, n_slots=2, smax=64)
-    warm = Request(rid=9, tenant="x", arrival_us=0, deadline_us=1e9,
-                   prompt=np.ones(3, np.int32), max_new=2)
-    churn.add(warm)
-    while churn.active():
-        churn.step()
-    assert run(churn) == solo
 
 
 def test_service_baseline_episode():

@@ -8,8 +8,11 @@ must retire the exact SLA / energy / per-tenant numbers of the same
 workload run through ``serve_trace_host`` (one dispatch per period,
 trace known upfront).  Everything the tick path does differently —
 masked-scatter admission, cumulative accumulators, ``commit_only``
-engine early exit — is pinned bit-for-bit here, for the specialist,
-the generalist, and a heuristic baseline.
+engine early exit — is pinned bit-for-bit here for several streams
+and for the generalist on every fleet preset; the specialist and the
+heuristic baselines on every fleet are pinned in
+``test_serving_parity_specialist.py`` and
+``test_serving_parity_baselines.py``.
 """
 import jax
 import jax.numpy as jnp
@@ -20,6 +23,7 @@ from repro.core import generalist as G
 from repro.core import policy as P
 from repro.core.serve import (host_layout, host_unpack, make_serving_tick,
                               queue_init_batch)
+from repro.costmodel.fleets import fleet_names
 from repro.serving import (LoadGenConfig, MultiTenantService, Request,
                            pack_admissions, per_tenant_metrics, queue_admit,
                            queue_init, queue_retire, request_stream,
@@ -32,40 +36,11 @@ from repro.workloads import build_registry
 
 CFG = EnvConfig(periods=10, max_rq=32, max_jobs=12)
 
-PARITY_KEYS = ("hits", "counted", "arrived", "sla_rate", "energy_uj")
-
-
-def _assert_parity(ref: dict, m: dict):
-    for k in PARITY_KEYS:
-        assert ref[k] == m[k], f"{k}: host {ref[k]} != batched {m[k]}"
-    assert ref["per_tenant"] == m["per_tenant"]
-
 
 # ---------------------------------------------------------------------------
 # host-loop vs single-dispatch tick: bit-identical on the same workload
 # ---------------------------------------------------------------------------
-def test_specialist_parity_host_vs_batched():
-    svc = MultiTenantService(build_registry("light"), policy="relmas",
-                             env_cfg=CFG)
-    for seed in (0, 3):
-        trace, _ = svc.env.new_episode(np.random.default_rng(seed))
-        ref = svc.serve_trace_host(trace, seed=seed)
-        out = svc.serve_stream(trace_to_requests(svc.env, trace),
-                               tick_k=CFG.max_jobs, seed=seed)
-        _assert_parity(ref, out["metrics"][0])
-
-
-def test_baseline_parity_host_vs_batched():
-    svc = MultiTenantService(build_registry("light"), policy="fcfs",
-                             env_cfg=CFG)
-    trace, _ = svc.env.new_episode(np.random.default_rng(1))
-    ref = svc.serve_trace_host(trace, seed=1)
-    out = svc.serve_stream(trace_to_requests(svc.env, trace),
-                           tick_k=CFG.max_jobs, seed=1)
-    _assert_parity(ref, out["metrics"][0])
-
-
-def test_multi_stream_parity_each_stream_matches_its_reference():
+def test_multi_stream_parity_each_stream_matches_its_reference(assert_parity):
     """The stream vmap axis must not couple queues: each of S streams
     retires exactly its own single-stream reference numbers."""
     svc = MultiTenantService(build_registry("light"), policy="relmas",
@@ -76,16 +51,17 @@ def test_multi_stream_parity_each_stream_matches_its_reference():
     out = svc.serve_stream([trace_to_requests(svc.env, tr) for tr in traces],
                            tick_k=CFG.max_jobs, seed=9)
     for ref, m in zip(refs, out["metrics"]):
-        _assert_parity(ref, m)
+        assert_parity(ref, m)
 
 
-def _generalist_service(m_max: int = 6, hidden: int = 8):
+def _generalist_service(m_max: int = 6, hidden: int = 8,
+                        fleet: str = "paper6"):
     """A generalist-serving service without a checkpoint on disk: the
     exact attribute set ``__init__``'s generalist branch produces, with
     freshly-initialized weights (parity only needs determinism)."""
     cfg = EnvConfig(periods=6, max_rq=16, max_jobs=8)
     svc = MultiTenantService.__new__(MultiTenantService)
-    svc.env = G.PaddedEnv(build_registry("light", mas="paper6"), cfg, m_max)
+    svc.env = G.PaddedEnv(build_registry("light", mas=fleet), cfg, m_max)
     spec = G.GeneralistSpec(m_max=m_max)
     svc.pcfg = spec.pcfg(hidden=hidden)
     svc.params = P.init_actor(jax.random.PRNGKey(7), svc.pcfg)
@@ -96,13 +72,14 @@ def _generalist_service(m_max: int = 6, hidden: int = 8):
     return svc
 
 
-def test_generalist_parity_host_vs_batched():
-    svc = _generalist_service()
+@pytest.mark.parametrize("fleet", fleet_names())
+def test_generalist_parity_host_vs_batched(fleet, assert_parity):
+    svc = _generalist_service(m_max=8, fleet=fleet)
     trace, _ = svc.env.new_episode(np.random.default_rng(2))
     ref = svc.serve_trace_host(trace, seed=2)
     out = svc.serve_stream(trace_to_requests(svc.env, trace),
                            tick_k=svc.env.cfg.max_jobs, seed=2)
-    _assert_parity(ref, out["metrics"][0])
+    assert_parity(ref, out["metrics"][0])
 
 
 def test_requests_to_trace_roundtrip_is_identity_on_real_rows():

@@ -1,0 +1,25 @@
+"""Each heuristic baseline's batched serving tick against its host
+loop, on every fleet preset: one trace served both ways retires the
+same SLA, energy and per-tenant numbers, bit for bit."""
+import numpy as np
+import pytest
+
+from repro.core import baselines as BL
+from repro.costmodel.fleets import fleet_names
+from repro.serving import MultiTenantService, trace_to_requests
+from repro.sim.env import EnvConfig
+from repro.workloads import build_registry
+
+CFG = EnvConfig(periods=10, max_rq=32, max_jobs=12)
+
+
+@pytest.mark.parametrize("policy", sorted(BL.BASELINES))
+@pytest.mark.parametrize("fleet", fleet_names())
+def test_baseline_parity_host_vs_batched(fleet, policy, assert_parity):
+    svc = MultiTenantService(build_registry("light", mas=fleet),
+                             policy=policy, env_cfg=CFG)
+    trace, _ = svc.env.new_episode(np.random.default_rng(1))
+    ref = svc.serve_trace_host(trace, seed=1)
+    out = svc.serve_stream(trace_to_requests(svc.env, trace),
+                           tick_k=CFG.max_jobs, seed=1)
+    assert_parity(ref, out["metrics"][0])

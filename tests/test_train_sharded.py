@@ -211,7 +211,7 @@ def test_devices_1_routes_to_plain_fused_path(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# 2-device subprocess tests (forced host devices, dryrun.py trick)
+# 2-device subprocess tests (host devices forced through XLA_FLAGS)
 # ---------------------------------------------------------------------------
 _PARITY_SCRIPT = r"""
 import os
