@@ -76,6 +76,15 @@ BUSY_COL = 4
 BUSY_CAP = 4.0
 
 
+def _take_one(hot, x):
+    """``x[j]`` for the one true ``j`` of each row of ``hot`` (R, J), as a
+    masked sum of x's 32-bit patterns: exact for every value (-0.0 and
+    NaN included), and a row with no true reads 0."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    s = jnp.sum(jnp.where(hot, bits[None, :], 0), axis=1, dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(s, x.dtype)
+
+
 @dataclasses.dataclass(frozen=True)
 class EnvConfig:
     t_s_us: float = 500.0        # scheduling period T_S
@@ -278,34 +287,48 @@ class SchedulingEnv:
     def build_slots(self, state: State, trace: Trace, cutoff) -> Slots:
         """Pack uncommitted layers of active jobs into R slots by deadline
         (of a re-entering job, the layers of its current pass only, by
-        the deadline of the token it yields)."""
-        cfg, R, J = self.cfg, self.cfg.max_rq, self.cfg.max_jobs
+        the deadline of the token it yields).
+
+        No sort, search or batched gather (on a v5e each batched gather,
+        and each trip of a search loop, took ~76 us of a 96-stream
+        serving tick): the stable deadline order is a (J, J) comparison,
+        each slot's job an (R, J) ownership mask, each per-job value a
+        masked sum over that job's one term, and the cost tables one 0/1
+        contraction.  Every field, invalid slots included, is what
+        ``argsort``, ``searchsorted`` and gathers give, bit for bit.
+        """
+        R, J = self.cfg.max_rq, self.cfg.max_jobs
         deadline = self.pass_deadline(state, trace)
         active = ((trace["arrival"] <= cutoff) & ~state["done"]
                   & ~state["missed"])
         rem = jnp.where(active, self.pass_end(state, trace) - state["nls"], 0)
         key = jnp.where(active & (rem > 0), deadline, INF)
-        order = jnp.argsort(key)                       # (J,)
-        rem_o = rem[order]
-        cum = jnp.cumsum(rem_o)
-        starts = cum - rem_o
-        total = cum[-1]
+        jj = jnp.arange(J)
+        # before[l, j]: job l precedes job j in stable argsort's order
+        before = ((key[:, None] < key[None, :])
+                  | ((key[:, None] == key[None, :])
+                     & (jj[:, None] < jj[None, :])))
+        starts = jnp.sum(jnp.where(before, rem[:, None], 0), axis=0)
+        total = jnp.sum(rem)
         i = jnp.arange(R)
-        k = jnp.searchsorted(cum, i, side="right")
-        k = jnp.clip(k, 0, J - 1)
         valid = i < jnp.minimum(total, R)
-        job = jnp.where(valid, order[k], 0)
-        layer = jnp.where(valid, state["nls"][job] + (i - starts[k]), 0)
+        own = (valid[:, None] & (starts[None, :] <= i[:, None])
+               & (i[:, None] < (starts + rem)[None, :]))   # (R, J)
+        job = jnp.sum(jnp.where(own, jj[None, :], 0), axis=1)
+        # an invalid slot reads job 0, as the gather x[job] would
+        jobhot = job[:, None] == jj[None, :]
+        at_job = lambda x: _take_one(jobhot, x)
+        layer = jnp.where(valid, at_job(state["nls"]) + (i - at_job(starts)),
+                          0)
         layer = jnp.clip(layer, 0, self.lmax - 1)
         prev_same = jnp.concatenate(
             [jnp.array([False]), (job[1:] == job[:-1]) & valid[1:] & valid[:-1]])
         dep = jnp.where(prev_same, i - 1, -1)
-        model = trace["model"][job]
+        model = at_job(trace["model"])
         ready_rel = jnp.where(
-            dep < 0, jnp.maximum(0.0, state["jready"][job] - state["t"]), 0.0)
-        cost_all = self.lat[model, layer]              # (R, M)
-        bw_all = self.bw[model, layer]
-        en_all = self.en[model, layer]
+            dep < 0, jnp.maximum(0.0, at_job(state["jready"]) - state["t"]),
+            0.0)
+        cost_all, bw_all, en_all = self.layer_costs(model, layer)
         # in-episode churn (rows injected by `period` when a schedule is
         # threaded — repro.sim.churn): a slowed SA advertises scaled
         # busy-times, a throttled SA scaled bus demand, an invalid SA a
@@ -327,8 +350,23 @@ class SchedulingEnv:
                     ready_rel=ready_rel * valid,
                     cost_all=cost_all * zero, bw_all=bw_all * zero,
                     en_all=en_all * zero, model=model,
-                    deadline=deadline[job], q=trace["q"][job],
-                    arrival=trace["arrival"][job])
+                    deadline=at_job(deadline), q=at_job(trace["q"]),
+                    arrival=at_job(trace["arrival"]))
+
+    def layer_costs(self, model, layer):
+        """``(lat, bw, en)[model, layer]``, each (R, M): one contraction
+        of the rows' 0/1 one-hot with the tables side by side.  At
+        HIGHEST precision the one non-zero term of a row is the table's
+        float32 exactly (TPU: 1.0 times the bfloat16 hi/mid/lo split of a
+        finite float32 sums back to it), so this equals the gather."""
+        M = self.num_sas
+        tables = jnp.concatenate([self.lat, self.bw, self.en], axis=-1)
+        tables = tables.reshape(-1, 3 * M)                # (n * Lmax, 3M)
+        row = model * self.lmax + layer
+        rowhot = (row[:, None] == jnp.arange(tables.shape[0])[None, :])
+        out = jnp.dot(rowhot.astype(jnp.float32), tables,
+                      precision=jax.lax.Precision.HIGHEST)
+        return out[:, :M], out[:, M:2 * M], out[:, 2 * M:]
 
     def encode(self, slots: Slots, state: State):
         """-> (feats (R+1, F), mask (R+1,)) with the primer at t=0."""

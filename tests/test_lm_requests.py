@@ -6,7 +6,9 @@ one-pass (CNN) program left as it was.
 re-entering jobs existed, recorded with the same shapes as below: the
 light-set tick's StableHLO (telemetry off and on; recorded again when
 the tick gained its packed host record ``out["host"]``, which adds the
-pack and that output and nothing else) and one episode of
+pack and that output and nothing else; and again when slot packing
+gave up its sort, search loop and gathers for bit-identical comparisons,
+selects and a 0/1 contraction) and one episode of
 ``SchedulingEnv.episode`` (every leaf's bytes). A change that means to
 alter the one-pass program records it again.
 """
